@@ -37,10 +37,30 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU grid spec; interpret mode supports it on CPU
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
+
+
+def _attend_block(q, k, v, ok, acc, m_scr, l_scr, *, scale, softcap):
+    """One online-softmax step of q [G, hd] against a k/v block [bk, hd].
+
+    ``ok(shape)`` gives the valid-key mask for the [G, bk] scores; the
+    running max / denominator / accumulator live in the scratch refs.
+    Shared by the dense and the paged kernel, so the two stay
+    block-for-block identical at equal block sizes.
+    """
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
+    if softcap:
+        s = jnp.tanh(s / softcap) * softcap
+    s = jnp.where(ok(s.shape), s, -jnp.inf)
+    m_prev = m_scr[...]                        # [G, 1]
+    m_new = jnp.maximum(m_prev[:, 0], s.max(-1))[:, None]
+    m_safe = jnp.maximum(m_new, -1e30)         # fully-masked block guard
+    p = jnp.exp(s - m_safe)
+    corr = jnp.exp(jnp.maximum(m_prev, -1e30) - m_safe)
+    l_scr[...] = l_scr[...] * corr + p.sum(-1)[:, None]
+    acc[...] = acc[...] * corr + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())))
+    m_scr[...] = m_new
 
 
 def _kernel(pos_ref, lo_ref, hi_ref, q_ref, k_ref, v_ref, o_ref,
@@ -59,37 +79,26 @@ def _kernel(pos_ref, lo_ref, hi_ref, q_ref, k_ref, v_ref, o_ref,
     lo = lo_ref[b]
     hi = hi_ref[b]
 
-    @pl.when(jnp.logical_and(ki >= lo, ki <= hi))
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)           # [G, hd]
-        k = k_ref[0, 0].astype(jnp.float32)           # [bk, hd]
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-        if softcap:
-            s = jnp.tanh(s / softcap) * softcap
-        idx = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    def ok(shape):
+        idx = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
         if ring:
             # slot i holds absolute position p with p % C == i; every
             # slot younger than the window is valid once written
             age = (pos_b - idx) % C
-            ok = age < (window if window else C)
-            ok &= pos_b >= age                # not yet written early on
+            m = age < (window if window else C)
+            m &= pos_b >= age                 # not yet written early on
         else:
-            ok = idx <= pos_b
+            m = idx <= pos_b
             if window:
-                ok &= idx > pos_b - window
-        ok &= idx < C                          # C % block_k padding guard
-        s = jnp.where(ok, s, -jnp.inf)
+                m &= idx > pos_b - window
+        return m & (idx < C)                   # C % block_k padding guard
 
-        m_prev = m_scr[...]                    # [G, 1]
-        m_new = jnp.maximum(m_prev[:, 0], s.max(-1))[:, None]
-        m_safe = jnp.maximum(m_new, -1e30)     # fully-masked block guard
-        p = jnp.exp(s - m_safe)
-        corr = jnp.exp(jnp.maximum(m_prev, -1e30) - m_safe)
-        l_scr[...] = l_scr[...] * corr + p.sum(-1)[:, None]
-        acc[...] = acc[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())))
-        m_scr[...] = m_new
+    @pl.when(jnp.logical_and(ki >= lo, ki <= hi))
+    def _compute():
+        _attend_block(q_ref[0, 0].astype(jnp.float32),        # [G, hd]
+                      k_ref[0, 0].astype(jnp.float32),        # [bk, hd]
+                      v_ref[0, 0].astype(jnp.float32), ok,
+                      acc, m_scr, l_scr, scale=scale, softcap=softcap)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -140,9 +149,6 @@ def decode_attention_fwd(q, k_cache, v_cache, pos, *, window=0, ring=False,
     Returns o [B, 1, H, hd] — same contract as
     ``models.layers.attention_decode``.
     """
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError("pallas TPU support unavailable in this jax "
-                           "build — use the XLA decode path")
     B, C, KV, hd = k_cache.shape
     H = q.shape[2]
     G = H // KV
@@ -175,9 +181,9 @@ def decode_attention_fwd(q, k_cache, v_cache, pos, *, window=0, ring=False,
         out_specs=pl.BlockSpec((1, 1, G, hd),
                                lambda b, h, j, *_: (b, h, 0, 0)),
         scratch_shapes=[
-            _scratch((G, hd)),
-            _scratch((G, 1)),
-            _scratch((G, 1)),
+            pltpu.VMEM((G, hd), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
         ],
     )
     kernel = functools.partial(
@@ -187,7 +193,8 @@ def decode_attention_fwd(q, k_cache, v_cache, pos, *, window=0, ring=False,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
-        compiler_params=_compiler_params(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(pos_b, lo, hi, qt, kt, vt)
     return out.reshape(B, 1, H, hd)
@@ -196,23 +203,25 @@ def decode_attention_fwd(q, k_cache, v_cache, pos, *, window=0, ring=False,
 def _paged_kernel(pos_ref, lo_ref, hi_ref, tbl_ref, act_ref,
                   q_ref, nk_ref, nv_ref, k_ref, v_ref,
                   o_ref, ko_ref, vo_ref, acc, m_scr, l_scr, *,
-                  scale, window, softcap, ps, npg):
+                  scale, window, softcap, ps, npg, kv_heads):
     """Fused write+attend over paged KV pools.
 
-    One grid step = one logical page of one (slot, kv-head); the k/v
-    index_maps resolve the page table in SMEM, so the kernel sweeps
-    *physical* pages while the masks reason in logical positions.  The
-    new token's K/V row never takes a separate scatter dispatch: at the
-    boundary page (``ki == hi``) the kernel splices the row into the
-    fetched block and emits it through the aliased pool output (the out
-    index_map pins the slot's write page — the null page 0 for inactive
-    slots), and the attention compute reads the row from the same
-    in-register splice, so scores never depend on the HBM write having
-    landed.  COW guarantees the write page's refcount is 1, so no other
-    slot can map it — the only cross-slot page traffic is reads.
+    One grid step = one logical page of one slot, all kv-heads at once
+    (a page block is ``[ps, KV, hd]``: its last two dims are whole, as
+    the TPU tiling wants); the k/v index_maps resolve the page table in
+    SMEM, so the kernel sweeps *physical* pages while the masks reason
+    in logical positions.  The new token's K/V row never takes a
+    separate scatter dispatch: at the boundary page (``ki == hi``) the
+    kernel splices the row into the fetched block and emits it through
+    the aliased pool output (the out index_map pins the slot's write
+    page — the null page 0 for inactive slots), and the attention
+    compute reads the row from the same in-register splice, so scores
+    never depend on the HBM write having landed.  COW guarantees the
+    write page's refcount is 1, so no other slot can map it — the only
+    cross-slot page traffic is reads.
     """
-    ki = pl.program_id(2)
     b = pl.program_id(0)
+    ki = pl.program_id(1)
 
     @pl.when(ki == 0)
     def _init():
@@ -223,46 +232,39 @@ def _paged_kernel(pos_ref, lo_ref, hi_ref, tbl_ref, act_ref,
     pos_b = pos_ref[b]
     lo = lo_ref[b]
     hi = hi_ref[b]
-    act = act_ref[b]
+    live = act_ref[b] > 0
     rows = jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0)
-    wsel = ((ki * ps + rows) == pos_b) & (act > 0)          # [ps, 1]
+    wsel = ((ki * ps + rows) == pos_b) & live                # [ps, 1]
 
     @pl.when(ki == hi)
     def _store():
-        ko_ref[0, :, 0, :] = jnp.where(wsel, nk_ref[0, 0][None, :],
-                                       k_ref[0, :, 0, :])
-        vo_ref[0, :, 0, :] = jnp.where(wsel, nv_ref[0, 0][None, :],
-                                       v_ref[0, :, 0, :])
+        w3 = wsel[:, :, None]
+        ko_ref[0] = jnp.where(w3, nk_ref[...].astype(ko_ref.dtype),
+                              k_ref[0])
+        vo_ref[0] = jnp.where(w3, nv_ref[...].astype(vo_ref.dtype),
+                              v_ref[0])
+
+    def ok(shape):
+        idx = ki * ps + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        m = idx <= pos_b
+        if window:
+            m &= idx > pos_b - window
+        return m
 
     @pl.when(jnp.logical_and(ki >= lo, ki <= hi))
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)                 # [G, hd]
-        k = jnp.where(wsel, nk_ref[0, 0][None, :],
-                      k_ref[0, :, 0, :]).astype(jnp.float32)  # [ps, hd]
-        v = jnp.where(wsel, nv_ref[0, 0][None, :],
-                      v_ref[0, :, 0, :]).astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-        if softcap:
-            s = jnp.tanh(s / softcap) * softcap
-        idx = ki * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        ok = idx <= pos_b
-        if window:
-            ok &= idx > pos_b - window
-        s = jnp.where(ok, s, -jnp.inf)
-
-        m_prev = m_scr[...]                                 # [G, 1]
-        m_new = jnp.maximum(m_prev[:, 0], s.max(-1))[:, None]
-        m_safe = jnp.maximum(m_new, -1e30)
-        p = jnp.exp(s - m_safe)
-        corr = jnp.exp(jnp.maximum(m_prev, -1e30) - m_safe)
-        l_scr[...] = l_scr[...] * corr + p.sum(-1)[:, None]
-        acc[...] = acc[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())))
-        m_scr[...] = m_new
+        for h in range(kv_heads):
+            k = jnp.where(wsel, nk_ref[0, pl.ds(h, 1), :],
+                          k_ref[0, :, h, :].astype(jnp.float32))  # [ps, hd]
+            v = jnp.where(wsel, nv_ref[0, pl.ds(h, 1), :],
+                          v_ref[0, :, h, :].astype(jnp.float32))
+            _attend_block(q_ref[0, h].astype(jnp.float32), k, v, ok,
+                          acc.at[h], m_scr.at[h], l_scr.at[h],
+                          scale=scale, softcap=softcap)
 
     @pl.when(ki == npg - 1)
     def _finalize():
-        o_ref[0, 0] = (acc[...] / jnp.maximum(l_scr[...], 1e-30)).astype(
+        o_ref[0] = (acc[...] / jnp.maximum(l_scr[...], 1e-30)).astype(
             o_ref.dtype)
 
 
@@ -288,7 +290,7 @@ def paged_decode_attention_fwd(q, new_k, new_v, k_pool, v_pool, pos,
     """Fused write+attend decode step over paged KV pools.
 
     q [B, 1, H, hd]; new_k/new_v [B, KV, hd] — the new token's K/V rows
-    (any float dtype; cast to the pool dtype before use so paged and
+    (any float dtype; rounded to the pool dtype before use so paged and
     dense streams stay bit-identical); k/v pools [P, ps, KV, hd];
     page_table [B, NP] int32 physical page per logical page; active [B]
     bool (inactive slots write nothing — their boundary block flushes
@@ -298,9 +300,6 @@ def paged_decode_attention_fwd(q, new_k, new_v, k_pool, v_pool, pos,
     ``ps == block_k`` the attention math is block-for-block identical
     to ``decode_attention_fwd`` on the gathered dense view.
     """
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError("pallas TPU support unavailable in this jax "
-                           "build — use the XLA decode path")
     P, ps, KV, hd = k_pool.shape
     B, NP = page_table.shape
     H = q.shape[2]
@@ -312,43 +311,47 @@ def paged_decode_attention_fwd(q, new_k, new_v, k_pool, v_pool, pos,
                           block_k=ps)
     act = jnp.asarray(active).astype(jnp.int32)
     qt = q.reshape(B, KV, G, hd)
-    nk = new_k.astype(k_pool.dtype)
-    nv = new_v.astype(v_pool.dtype)
+    # the new rows ride in f32 (already rounded to the pool dtype): a
+    # 32-bit [KV, hd] tile can be indexed per head without sublane
+    # packing, and the store casts back exactly
+    nk = new_k.astype(k_pool.dtype).astype(jnp.float32)
+    nv = new_v.astype(v_pool.dtype).astype(jnp.float32)
 
-    def kv_map(b, h, j, pos_ref, lo_ref, hi_ref, tbl_ref, act_ref):
+    def kv_map(b, j, pos_ref, lo_ref, hi_ref, tbl_ref, act_ref):
         # page-table indirection in SMEM; the clamp makes out-of-range
         # grid steps re-visit the boundary page (no DMA, no compute)
-        return tbl_ref[b, jnp.clip(j, lo_ref[b], hi_ref[b])], 0, h, 0
+        return tbl_ref[b, jnp.clip(j, lo_ref[b], hi_ref[b])], 0, 0, 0
 
-    def wr_map(b, h, j, pos_ref, lo_ref, hi_ref, tbl_ref, act_ref):
-        # constant per (b, h): the slot's write page, flushed once at
-        # the sweep boundary with the spliced block from _store
-        return jnp.where(act_ref[b] > 0, tbl_ref[b, hi_ref[b]], 0), 0, h, 0
+    def wr_map(b, j, pos_ref, lo_ref, hi_ref, tbl_ref, act_ref):
+        # constant per slot: the slot's write page, flushed once at the
+        # sweep boundary with the spliced block from _store
+        return jnp.where(act_ref[b] > 0, tbl_ref[b, hi_ref[b]], 0), 0, 0, 0
 
+    page = (1, ps, KV, hd)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
-        grid=(B, KV, NP),
+        grid=(B, NP),
         in_specs=[
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, j, *_: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, hd), lambda b, h, j, *_: (b, h, 0)),
-            pl.BlockSpec((1, 1, hd), lambda b, h, j, *_: (b, h, 0)),
-            pl.BlockSpec((1, ps, 1, hd), kv_map),
-            pl.BlockSpec((1, ps, 1, hd), kv_map),
+            pl.BlockSpec((1, KV, G, hd), lambda b, j, *_: (b, 0, 0, 0)),
+            pl.BlockSpec((1, KV, hd), lambda b, j, *_: (b, 0, 0)),
+            pl.BlockSpec((1, KV, hd), lambda b, j, *_: (b, 0, 0)),
+            pl.BlockSpec(page, kv_map),
+            pl.BlockSpec(page, kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, j, *_: (b, h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, hd), wr_map),
-            pl.BlockSpec((1, ps, 1, hd), wr_map),
+            pl.BlockSpec((1, KV, G, hd), lambda b, j, *_: (b, 0, 0, 0)),
+            pl.BlockSpec(page, wr_map),
+            pl.BlockSpec(page, wr_map),
         ],
         scratch_shapes=[
-            _scratch((G, hd)),
-            _scratch((G, 1)),
-            _scratch((G, 1)),
+            pltpu.VMEM((KV, G, hd), jnp.float32),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
         ],
     )
     kernel = functools.partial(
         _paged_kernel, scale=scale, window=window, softcap=softcap,
-        ps=ps, npg=NP)
+        ps=ps, npg=NP, kv_heads=KV)
     o, kp, vp = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -359,35 +362,13 @@ def paged_decode_attention_fwd(q, new_k, new_v, k_pool, v_pool, pos,
         ],
         # operand numbering includes the 5 scalar-prefetch args
         input_output_aliases={8: 1, 9: 2},
-        compiler_params=_paged_compiler_params(),
+        # both dims "arbitrary": slots read pages other slots may be
+        # flushing their boundary block to (shared prefix pages are
+        # read-only, but the in/out pool aliasing still wants a defined
+        # step order)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(pos_b, lo, hi, jnp.asarray(page_table, jnp.int32), act, qt, nk, nv,
       k_pool, v_pool)
     return o.reshape(B, 1, H, hd), kp, vp
-
-
-def _scratch(shape):
-    try:
-        return pltpu.VMEM(shape, jnp.float32)
-    except Exception:  # pragma: no cover
-        return pl.MemorySpace.ANY(shape, jnp.float32)  # type: ignore
-
-
-def _compiler_params():
-    try:
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except Exception:  # pragma: no cover
-        return None
-
-
-def _paged_compiler_params():
-    # every dim "arbitrary": slots read pages other slots may be
-    # flushing their boundary block to (shared prefix pages are
-    # read-only, but the in/out pool aliasing still wants a defined
-    # step order)
-    try:
-        return pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"))
-    except Exception:  # pragma: no cover
-        return None

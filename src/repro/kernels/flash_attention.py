@@ -24,6 +24,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, acc, m_scr, l_scr,
@@ -113,29 +114,13 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=0, scale=None,
                                lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
         scratch_shapes=[
-            _scratch((block_q, hd)),
-            _scratch((block_q, 1)),
-            _scratch((block_q, 1)),
+            pltpu.VMEM((block_q, hd), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=_compiler_params(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
     )(qt, kt, vt)
     return out.swapaxes(1, 2)
-
-
-def _scratch(shape):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.VMEM(shape, jnp.float32)
-    except Exception:  # pragma: no cover
-        return pl.MemorySpace.ANY(shape, jnp.float32)  # type: ignore
-
-
-def _compiler_params():
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
-    except Exception:  # pragma: no cover
-        return None

@@ -32,12 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU memory spaces; interpret mode ignores them on CPU
-    from jax.experimental.pallas import tpu as pltpu
-    SMEM = pltpu.SMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    SMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 # scalar layout: [lr, b1, b2, eps, wd, bc1, bc2, tau]
 N_SCALARS = 8
@@ -111,8 +106,7 @@ def masked_adam_q8_2d(p, g, mq, ms, vq, vs, mask, scalars, *, use_tau=False,
 
     tile = lambda: pl.BlockSpec((block_r, C), lambda i: (i, 0))
     srow = lambda: pl.BlockSpec((block_r, 1), lambda i: (i, 0))
-    scal_spec = (pl.BlockSpec(memory_space=SMEM) if SMEM is not None
-                 else pl.BlockSpec((N_SCALARS,), lambda i: (0,)))
+    scal_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     kernel = functools.partial(_q8_kernel, use_tau=use_tau)
     return pl.pallas_call(
         kernel,
@@ -146,8 +140,7 @@ def masked_adam_2d(p, g, m, v, mask, scalars, *, use_tau=False,
         return (i, j)
 
     tile = lambda: pl.BlockSpec((block_r, block_c), idx)
-    scal_spec = (pl.BlockSpec(memory_space=SMEM) if SMEM is not None
-                 else pl.BlockSpec((N_SCALARS,), lambda i, j: (0,)))
+    scal_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     kernel = functools.partial(_kernel, use_tau=use_tau)
     return pl.pallas_call(
         kernel,

@@ -19,6 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(a_ref, b_ref, h0_ref, y_ref, hN_ref, h_scr, *, ns, block_t):
@@ -80,25 +81,9 @@ def rglru_scan_kernel(a, b, h0, *, block_t=128, block_w=512,
             jax.ShapeDtypeStruct((B, S + pad_t, W), jnp.float32),
             jax.ShapeDtypeStruct((B, W), jnp.float32),
         ],
-        scratch_shapes=[_scratch((block_w,))],
-        compiler_params=_compiler_params(),
+        scratch_shapes=[pltpu.VMEM((block_w,), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, h0)
     return y[:, :S], hN
-
-
-def _scratch(shape):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.VMEM(shape, jnp.float32)
-    except Exception:  # pragma: no cover
-        return None
-
-
-def _compiler_params():
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except Exception:  # pragma: no cover
-        return None

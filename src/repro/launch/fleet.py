@@ -102,6 +102,8 @@ def main(argv=None):
         args.new_tokens = min(args.new_tokens, 8)
         args.reduce = max(args.reduce, 8)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import jax
     import numpy as np
     from repro.configs import base as config_base

@@ -15,23 +15,12 @@ parallelism across DCN).  Axis semantics:
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5 has explicit axis types; older jax is Auto-only
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh_compat(shape, axis_names) -> Mesh:
-    """``jax.make_mesh`` with Auto axis types across jax versions.
-
-    ``axis_types`` (and ``jax.sharding.AxisType``) only exist in newer
-    jax; on older versions every axis is implicitly Auto, which is
-    exactly what we request — so omitting the kwarg is equivalent.
-    """
-    if AxisType is None:
-        return jax.make_mesh(shape, axis_names)
+    """``jax.make_mesh`` with every axis Auto (sharding left to GSPMD,
+    as the ``shard_ctx`` constraints expect)."""
     return jax.make_mesh(shape, axis_names,
                          axis_types=(AxisType.Auto,) * len(axis_names))
 

@@ -184,6 +184,7 @@ def make_demo_registry(params, n: int):
 
 
 def main(argv=None):
+    """Serve the request set; returns ``(requests, drained server)``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama-60m")
     ap.add_argument("--reduce", type=int, default=4)
@@ -221,6 +222,8 @@ def main(argv=None):
         args.new_tokens = min(args.new_tokens, 8)
         args.reduce = max(args.reduce, 8)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import jax
     import numpy as np
     from repro.configs import base as config_base
@@ -351,7 +354,7 @@ def main(argv=None):
     for r in reqs[:3]:
         tag = f" [{r.adapter_id or 'base'}]"
         print(f"  req {r.rid}{tag}: {list(r.prompt)} -> {r.out}")
-    return reqs
+    return reqs, srv
 
 
 if __name__ == "__main__":

@@ -90,7 +90,9 @@ def make_trainer(cfg, args, params=None):
         core, core.init(jax.random.PRNGKey(args.seed), params))
 
 
-def main(argv=None):
+def main(argv=None, *, on_step=None):
+    """Run the launcher; ``on_step(step, metrics)`` is called after each
+    train step (see ``runtime.train_loop.run``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama-60m")
     ap.add_argument("--steps", type=int, default=100)
@@ -139,6 +141,8 @@ def main(argv=None):
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " "
                                    + TPU_PERF_FLAGS)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.configs import base as config_base
     from repro.data.pipeline import DataConfig, TokenPipeline
     from repro.runtime.train_loop import TrainLoopConfig, run
@@ -177,7 +181,7 @@ def main(argv=None):
                               ckpt_every=args.ckpt_every,
                               ckpt_dir=args.ckpt_dir,
                               metrics_every=args.metrics_every),
-              tracer=tracer, metrics=metrics)
+              on_step=on_step, tracer=tracer, metrics=metrics)
     rep = trainer.memory_report()
     print(f"final loss: {out['losses'][-1]:.4f}")
     print("memory report:", {k: f"{v/2**20:.1f}MiB" for k, v in rep.items()})

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.runtime.shard_compat import shard_map
 
 Pytree = Any
 BLOCK = 256
@@ -79,7 +78,7 @@ def compressed_psum_tree(grads: Pytree, errors: Pytree, mesh, dp_axes,
         return (td.unflatten([o[0] for o in out]),
                 td.unflatten([o[1] for o in out]))
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P()), out_specs=(P(), P()),
         axis_names=set(dp), check_vma=False)

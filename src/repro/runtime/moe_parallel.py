@@ -23,7 +23,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.models import moe as moe_lib
 from repro.runtime import shard_ctx
-from repro.runtime.shard_compat import shard_map
 
 TP = "model"
 
@@ -65,7 +64,7 @@ def moe_apply_maybe_sharded(params, x, cfg):
             aux = jax.lax.pmean(aux, dp)
         return y, aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(_moe_param_specs(params, cfg, mesh, tp_ok),

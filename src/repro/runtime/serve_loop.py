@@ -160,12 +160,9 @@ def _lane(adapter_id: Optional[str]) -> str:
 
 
 def _jit_cache_size(fn) -> int:
-    """Compiled-entry count of a jitted fn (-1 when the jax version does
-    not expose it).  Growth across a call == that call compiled."""
-    try:
-        return fn._cache_size()
-    except Exception:
-        return -1
+    """Compiled-entry count of a jitted fn.  Growth across a call ==
+    that call compiled."""
+    return fn._cache_size()
 
 
 @functools.lru_cache(maxsize=None)
@@ -934,7 +931,7 @@ class DecodeServer:
                     jnp.asarray(lengths))
             if tr is not None:
                 t1 = time.monotonic_ns()
-                compiled = _jit_cache_size(pf) > before >= 0
+                compiled = _jit_cache_size(pf) > before
                 tr.add_span("prefill", t0, t1, lane="sched", kind="chunk",
                             start=start, chunk=k, compiled=compiled)
                 if compiled:
@@ -1008,8 +1005,7 @@ class DecodeServer:
         nxt = np.asarray(jnp.argmax(logits, -1))  # host sync point
         t1_ns = time.monotonic_ns()
         after = _jit_cache_size(self._decode)
-        # no _cache_size() on this jax: fall back to skip-first-step
-        compiled = (after > before) if before >= 0 else (self.steps == 0)
+        compiled = after > before
         dt = (t1_ns - t0_ns) / 1e6
         if compiled:
             self.metrics.counter("sched/compiles").inc()
@@ -1162,8 +1158,7 @@ class DecodeServer:
                         step=self.steps, n=n + 1, batch=int(mask.sum()))
         after = _jit_cache_size(self._decode)
         vafter = _jit_cache_size(self._verify)
-        compiled = ((after > before or vafter > vbefore)
-                    if before >= 0 and vbefore >= 0 else self.steps == 0)
+        compiled = after > before or vafter > vbefore
         if compiled:
             m.counter("sched/compiles").inc()
             if tr is not None:

@@ -14,7 +14,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.runtime import shard_ctx
-from repro.runtime.shard_compat import shard_map
 
 
 def _batch_specs(tree, dp):
@@ -35,7 +34,7 @@ def block_shard_map(fn, params, x, cache):
     out_shape = jax.eval_shape(fn, params, x, cache)
     out_specs = (_batch_specs(out_shape[0], dp),
                  _batch_specs(out_shape[1], dp))
-    sm = shard_map(
+    sm = jax.shard_map(
         fn, mesh=ctx.mesh,
         in_specs=(P(), P(dp, None, None), _batch_specs(cache, dp)),
         out_specs=out_specs,

@@ -40,9 +40,9 @@ def test_train_launcher_resumes(tmp_path):
 
 def test_serve_launcher():
     from repro.launch.serve import main
-    reqs = main(["--arch", "llama-60m", "--reduce", "8", "--slots", "2",
-                 "--requests", "3", "--new-tokens", "4",
-                 "--max-seq", "32"])
+    reqs, _ = main(["--arch", "llama-60m", "--reduce", "8", "--slots", "2",
+                    "--requests", "3", "--new-tokens", "4",
+                    "--max-seq", "32"])
     assert all(len(r.out) == 4 for r in reqs)
 
 
