@@ -105,14 +105,16 @@ print(f"re-selections: {state.meta['reselections']}, "
 #    registry (decode/*, prefill/*, sched/*) as greppable text, and
 #    `DecodeServer.stats()` returns the same numbers as nested
 #    sections.  Training mirrors it: `launch.train --trace t.jsonl`
-#    records per-step spans (data/step/ckpt/export lanes) plus BlockLLM
-#    selection telemetry per step — sel_q (selected fraction), sel_churn
-#    (Jaccard distance between consecutive plans), sel_grad_concentration
-#    (gradient-energy share of the selected blocks),
-#    sel_steps_since_reselect.  Kernel-level timing is opt-in:
-#    `repro.kernels.ops.enable_kernel_profiling(tracer, metrics)` wraps
-#    each Pallas op call with block-until-ready timing and its analytic
-#    bytes model (achieved GB/s next to the roofline).  Traces are
+#    records per-step spans (data/step/ckpt/export lanes, the BlockLLM
+#    step's phases inside `train_step`, and a `compile` lane) plus
+#    BlockLLM selection telemetry per step — sel_q (selected fraction),
+#    sel_churn (Jaccard distance between consecutive plans),
+#    sel_grad_concentration (gradient-energy share of the selected
+#    blocks), sel_steps_since_reselect.  Under `jax.profiler` the same
+#    spans land in the profiler's host plane, and the device ops of the
+#    train step carry the named scopes `blockllm_grad`,
+#    `blockllm_grad_norms`, `blockllm_mask_refresh` and
+#    `blockllm_update`: kernels are timed from that trace.  Traces are
 #    CI-validated by tools/check_trace.py (the trace-smoke job);
 #    benchmarks accept --trace-dir to emit one trace per measured leg.
 

@@ -111,68 +111,75 @@ def build_step_fn(cfg, index: UnitIndex, adam: Adam, bcfg: BlockLLMConfig,
                 merged[name] = sub
             return loss_fn(merged, batch, overlay=overlay)
 
-        (loss, metrics), grads = jax.value_and_grad(
-            lossf, argnums=(0, 1), has_aux=True)(sel, probe)
+        with jax.named_scope("blockllm_grad"):
+            (loss, metrics), grads = jax.value_and_grad(
+                lossf, argnums=(0, 1), has_aux=True)(sel, probe)
         g_sel, g_probe = grads
 
         # per-unit gradient norms -> host norm dictionary
-        norm_out = {"stacks": {}, "leaves": {}, "probe": {}}
-        for sid, rows in g_sel["stacks"].items():
-            norm_out["stacks"][sid] = units_lib.per_row_sq_norms(rows)
-        for name, sub in g_sel["leaves"].items():
-            norm_out["leaves"][name] = units_lib.subtree_sq_norm(sub)
-        for sid, rows in g_probe.items():
-            norm_out["probe"][sid] = units_lib.per_row_sq_norms(rows)
+        with jax.named_scope("blockllm_grad_norms"):
+            norm_out = {"stacks": {}, "leaves": {}, "probe": {}}
+            for sid, rows in g_sel["stacks"].items():
+                norm_out["stacks"][sid] = units_lib.per_row_sq_norms(rows)
+            for name, sub in g_sel["leaves"].items():
+                norm_out["leaves"][name] = units_lib.subtree_sq_norm(sub)
+            for sid, rows in g_probe.items():
+                norm_out["probe"][sid] = units_lib.per_row_sq_norms(rows)
 
         if refresh:
-            upds, _ = adam.processed_grad(g_sel, opt_state)
+            with jax.named_scope("blockllm_mask_refresh"):
+                upds, _ = adam.processed_grad(g_sel, opt_state)
 
-            def stack_mask(u):  # per-row (=per-layer) tau — paper's mask
-                tau = _masked_quantile_threshold(u, q, bcfg.quantile_sample)
-                return jnp.abs(u) >= tau.reshape(
-                    (-1,) + (1,) * (u.ndim - 1))
+                def stack_mask(u):  # per-row (=per-layer) tau — paper's mask
+                    tau = _masked_quantile_threshold(
+                        u, q, bcfg.quantile_sample)
+                    return jnp.abs(u) >= tau.reshape(
+                        (-1,) + (1,) * (u.ndim - 1))
 
-            def leaf_mask(u):  # whole-leaf unit: one tau per tensor
-                tau = _masked_quantile_threshold(
-                    u.reshape(1, -1), q, bcfg.quantile_sample)[0]
-                return jnp.abs(u) >= tau
+                def leaf_mask(u):  # whole-leaf unit: one tau per tensor
+                    tau = _masked_quantile_threshold(
+                        u.reshape(1, -1), q, bcfg.quantile_sample)[0]
+                    return jnp.abs(u) >= tau
 
-            new_masks = {
-                "stacks": jax.tree.map(stack_mask, upds["stacks"]),
-                "leaves": jax.tree.map(leaf_mask, upds["leaves"]),
-            }
+                new_masks = {
+                    "stacks": jax.tree.map(stack_mask, upds["stacks"]),
+                    "leaves": jax.tree.map(leaf_mask, upds["leaves"]),
+                }
         else:
             new_masks = masks
 
-        if bcfg.fused_update != "off" and not refresh:
-            # fused masked-Adam Pallas kernel: one VMEM pass per tile
-            # (5 reads + 3 writes vs ~12 HBM round-trips unfused)
-            from repro.kernels import ops as kernel_ops
-            from repro.optim.q8adam import Q8Adam, Q8AdamState
-            lr = adam.lr(opt_state.count) if callable(adam.lr) else adam.lr
-            mask_arg = new_masks if (with_masks or refresh) else None
-            if isinstance(adam, Q8Adam):
-                # Q8State: moments stream through VMEM as int8+scale —
-                # dequant/requant fused, no fp32 moment HBM round-trip
-                new_sel, mq2, ms2, nq2, ns2 = kernel_ops.masked_adam_q8_tree(
-                    sel, g_sel, opt_state.mu_q, opt_state.mu_scale,
-                    opt_state.nu_q, opt_state.nu_scale, mask_arg,
-                    lr=lr, b1=adam.b1, b2=adam.b2, eps=adam.eps,
-                    weight_decay=adam.weight_decay, count=opt_state.count,
-                    interpret=(bcfg.fused_update == "interpret"))
-                new_opt = Q8AdamState(opt_state.count + 1, mq2, ms2,
-                                      nq2, ns2)
+        with jax.named_scope("blockllm_update"):
+            if bcfg.fused_update != "off" and not refresh:
+                # fused masked-Adam Pallas kernel: one VMEM pass per tile
+                # (5 reads + 3 writes vs ~12 HBM round-trips unfused)
+                from repro.kernels import ops as kernel_ops
+                from repro.optim.q8adam import Q8Adam, Q8AdamState
+                lr = (adam.lr(opt_state.count) if callable(adam.lr)
+                      else adam.lr)
+                mask_arg = new_masks if (with_masks or refresh) else None
+                if isinstance(adam, Q8Adam):
+                    # Q8State: moments stream through VMEM as int8+scale —
+                    # dequant/requant fused, no fp32 moment HBM round-trip
+                    (new_sel, mq2, ms2, nq2,
+                     ns2) = kernel_ops.masked_adam_q8_tree(
+                        sel, g_sel, opt_state.mu_q, opt_state.mu_scale,
+                        opt_state.nu_q, opt_state.nu_scale, mask_arg,
+                        lr=lr, b1=adam.b1, b2=adam.b2, eps=adam.eps,
+                        weight_decay=adam.weight_decay, count=opt_state.count,
+                        interpret=(bcfg.fused_update == "interpret"))
+                    new_opt = Q8AdamState(opt_state.count + 1, mq2, ms2,
+                                          nq2, ns2)
+                else:
+                    new_sel, mu2, nu2 = kernel_ops.masked_adam_tree(
+                        sel, g_sel, opt_state.mu, opt_state.nu, mask_arg,
+                        lr=lr, b1=adam.b1, b2=adam.b2, eps=adam.eps,
+                        weight_decay=adam.weight_decay, count=opt_state.count,
+                        interpret=(bcfg.fused_update == "interpret"))
+                    new_opt = AdamState(opt_state.count + 1, mu2, nu2)
             else:
-                new_sel, mu2, nu2 = kernel_ops.masked_adam_tree(
-                    sel, g_sel, opt_state.mu, opt_state.nu, mask_arg,
-                    lr=lr, b1=adam.b1, b2=adam.b2, eps=adam.eps,
-                    weight_decay=adam.weight_decay, count=opt_state.count,
-                    interpret=(bcfg.fused_update == "interpret"))
-                new_opt = AdamState(opt_state.count + 1, mu2, nu2)
-        else:
-            new_sel, new_opt = adam.update(
-                g_sel, opt_state, sel,
-                update_mask=new_masks if with_masks or refresh else None)
+                new_sel, new_opt = adam.update(
+                    g_sel, opt_state, sel,
+                    update_mask=new_masks if with_masks or refresh else None)
         return new_sel, new_opt, new_masks, loss, metrics, norm_out
 
     return step

@@ -33,88 +33,6 @@ def pallas_available() -> bool:
 
 
 # --------------------------------------------------------------------- #
-# opt-in kernel profiling (TraceKit)
-# --------------------------------------------------------------------- #
-#
-# ``enable_kernel_profiling()`` wraps every public op below with a
-# block-until-ready wall timing plus (where an analytic model exists)
-# the bytes the op moves — achieved GB/s next to the roofline number.
-# Disabled (the default) the wrappers fall through with a single
-# ``is None`` check.  Calls made from INSIDE a jit trace (abstract
-# ``jax.core.Tracer`` leaves) always pass through untimed: blocking on
-# traced values is meaningless and would break tracing.
-
-
-class KernelProfiler:
-    """Collects per-op timing records; optionally forwards to a
-    ``repro.obs`` tracer (lane ``kernels``) and metrics registry."""
-
-    def __init__(self, tracer=None, metrics=None):
-        self.tracer = tracer
-        self.metrics = metrics
-        self.records = []
-
-    def record(self, op: str, t0_ns: int, t1_ns: int, nbytes):
-        dt_ms = (t1_ns - t0_ns) / 1e6
-        rec = {"op": op, "ms": dt_ms, "bytes": nbytes,
-               "gbps": (nbytes / ((t1_ns - t0_ns) / 1e9) / 1e9
-                        if nbytes and t1_ns > t0_ns else None)}
-        self.records.append(rec)
-        if self.tracer is not None:
-            args = {"bytes": nbytes} if nbytes else {}
-            if rec["gbps"] is not None:
-                args["gbps"] = round(rec["gbps"], 3)
-            self.tracer.add_span(op, t0_ns, t1_ns, lane="kernels", **args)
-        if self.metrics is not None:
-            self.metrics.counter(f"kernels/{op}_calls").inc()
-            self.metrics.histogram(f"kernels/{op}_ms").observe(dt_ms)
-
-    def summary(self):
-        out = {}
-        for r in self.records:
-            s = out.setdefault(r["op"], {"calls": 0, "ms": 0.0,
-                                         "bytes": 0})
-            s["calls"] += 1
-            s["ms"] += r["ms"]
-            s["bytes"] += r["bytes"] or 0
-        return out
-
-
-_PROFILER: "KernelProfiler | None" = None
-
-
-def enable_kernel_profiling(tracer=None, metrics=None) -> KernelProfiler:
-    global _PROFILER
-    _PROFILER = KernelProfiler(tracer=tracer, metrics=metrics)
-    return _PROFILER
-
-
-def disable_kernel_profiling() -> None:
-    global _PROFILER
-    _PROFILER = None
-
-
-def _profiled_call(op: str, fn, args, kwargs, nbytes=None):
-    prof = _PROFILER
-    if prof is None:
-        return fn(*args, **kwargs)
-    import time
-    if any(isinstance(x, jax.core.Tracer)
-           for x in jax.tree.leaves((args, kwargs))):
-        return fn(*args, **kwargs)   # inside jit: cannot block/time
-    t0 = time.monotonic_ns()
-    out = fn(*args, **kwargs)
-    jax.block_until_ready(out)
-    prof.record(op, t0, time.monotonic_ns(), nbytes)
-    return out
-
-
-def _tree_nbytes(*trees) -> int:
-    return sum(getattr(x, "nbytes", 0) for t in trees
-               for x in jax.tree.leaves(t))
-
-
-# --------------------------------------------------------------------- #
 # flash attention with XLA backward
 # --------------------------------------------------------------------- #
 
@@ -148,34 +66,14 @@ _flash_attention_op.defvjp(_fa_fwd, _fa_bwd)
 
 
 def flash_attention(q, k, v, causal=True, window=0, interpret=False):
-    """Public entry: the custom_vjp op behind the profiling gate (the
-    wrapper is transparent to autodiff — grad reaches the custom_vjp)."""
-    if _PROFILER is None:
-        return _flash_attention_op(q, k, v, causal, window, interpret)
-    # bytes touched: read q/k/v once, write o (q-shaped)
-    nb = q.nbytes * 2 + k.nbytes + v.nbytes
-    return _profiled_call("flash_attention", _flash_attention_op,
-                          (q, k, v, causal, window, interpret), {}, nb)
+    """Public entry: the custom_vjp op (transparent to autodiff — grad
+    reaches the custom_vjp)."""
+    return _flash_attention_op(q, k, v, causal, window, interpret)
 
 
 # --------------------------------------------------------------------- #
 # fused decode attention (serving hot path; no backward — inference only)
 # --------------------------------------------------------------------- #
-
-
-def _decode_attention_impl(q, k_cache, v_cache, pos, *, window=0,
-                           ring=False, softcap=0.0, mode="auto",
-                           block_k=128):
-    if mode == "auto":
-        mode = "pallas" if pallas_available() else "xla"
-    if mode == "xla":
-        return layers.attention_decode(q, k_cache, v_cache, pos,
-                                       window=window, softcap=softcap,
-                                       ring=ring)
-    return da.decode_attention_fwd(q, k_cache, v_cache, pos, window=window,
-                                   ring=ring, softcap=softcap,
-                                   block_k=block_k,
-                                   interpret=(mode == "interpret"))
 
 
 def decode_attention(q, k_cache, v_cache, pos, *, window=0, ring=False,
@@ -188,31 +86,30 @@ def decode_attention(q, k_cache, v_cache, pos, *, window=0, ring=False,
     scale with ``pos`` (see kernels/decode_attention.py); the XLA path
     scores the full cache but never materializes GQA-repeated heads.
     """
-    kw = dict(window=window, ring=ring, softcap=softcap, mode=mode,
-              block_k=block_k)
-    if _PROFILER is None:
-        return _decode_attention_impl(q, k_cache, v_cache, pos, **kw)
-    # analytic achieved-vs-roofline bytes: the fused kernel's cache reads
-    # scale with pos; the XLA fallback reads the whole cache every step
-    try:
-        eff = mode if mode != "auto" else (
-            "pallas" if pallas_available() else "xla")
-        if eff == "xla":
-            nb = q.nbytes + k_cache.nbytes + v_cache.nbytes
-        else:
-            nb = q.nbytes + da.cache_read_bytes(
-                pos, seq_len=k_cache.shape[1], kv_heads=k_cache.shape[2],
-                head_dim=k_cache.shape[3], window=window, ring=ring,
-                block_k=block_k, dtype_bytes=k_cache.dtype.itemsize)
-    except Exception:
-        nb = None
-    return _profiled_call("decode_attention", _decode_attention_impl,
-                          (q, k_cache, v_cache, pos), kw, nb)
+    if mode == "auto":
+        mode = "pallas" if pallas_available() else "xla"
+    if mode == "xla":
+        return layers.attention_decode(q, k_cache, v_cache, pos,
+                                       window=window, softcap=softcap,
+                                       ring=ring)
+    return da.decode_attention_fwd(q, k_cache, v_cache, pos, window=window,
+                                   ring=ring, softcap=softcap,
+                                   block_k=block_k,
+                                   interpret=(mode == "interpret"))
 
 
-def _paged_decode_attention_impl(q, new_k, new_v, k_pool, v_pool, pos,
-                                 page_table, active, *, window=0,
-                                 softcap=0.0, mode="auto"):
+def paged_decode_attention(q, new_k, new_v, k_pool, v_pool, pos, page_table,
+                           active, *, window=0, softcap=0.0,
+                           mode: str = "auto"):
+    """One-token fused write+attend against paged KV pools.
+
+    q [B, 1, H, hd]; new_k/new_v [B, KV, hd] (the new token's rows);
+    pools [P, page_size, KV, hd]; page_table [B, NP] int32; active [B]
+    bool.  Returns ``(o, k_pool', v_pool')`` — the row write happens
+    inside the op (kernel prologue on the Pallas path), so the serving
+    loop dispatches one op per layer instead of scatter + attend.
+    ``mode``: ``pallas`` | ``interpret`` | ``xla`` | ``auto``.
+    """
     if mode == "auto":
         mode = "pallas" if pallas_available() else "xla"
     if mode != "xla":
@@ -244,43 +141,6 @@ def _paged_decode_attention_impl(q, new_k, new_v, k_pool, v_pool, pos,
     return o, kf.reshape(k_pool.shape), vf.reshape(v_pool.shape)
 
 
-def paged_decode_attention(q, new_k, new_v, k_pool, v_pool, pos, page_table,
-                           active, *, window=0, softcap=0.0,
-                           mode: str = "auto"):
-    """One-token fused write+attend against paged KV pools.
-
-    q [B, 1, H, hd]; new_k/new_v [B, KV, hd] (the new token's rows);
-    pools [P, page_size, KV, hd]; page_table [B, NP] int32; active [B]
-    bool.  Returns ``(o, k_pool', v_pool')`` — the row write happens
-    inside the op (kernel prologue on the Pallas path), so the serving
-    loop dispatches one op per layer instead of scatter + attend.
-    ``mode``: ``pallas`` | ``interpret`` | ``xla`` | ``auto``.
-    """
-    kw = dict(window=window, softcap=softcap, mode=mode)
-    if _PROFILER is None:
-        return _paged_decode_attention_impl(q, new_k, new_v, k_pool, v_pool,
-                                            pos, page_table, active, **kw)
-    try:
-        eff = mode if mode != "auto" else (
-            "pallas" if pallas_available() else "xla")
-        if eff == "xla":
-            # the gather materializes a dense view: read pools + write row
-            nb = q.nbytes + k_pool.nbytes + v_pool.nbytes \
-                + new_k.nbytes + new_v.nbytes
-        else:
-            nb = q.nbytes + da.paged_cache_read_bytes(
-                pos, num_pages_per_slot=page_table.shape[1],
-                page_size=k_pool.shape[1], kv_heads=k_pool.shape[2],
-                head_dim=k_pool.shape[3], window=window,
-                dtype_bytes=k_pool.dtype.itemsize)
-    except Exception:
-        nb = None
-    return _profiled_call("paged_decode_attention",
-                          _paged_decode_attention_impl,
-                          (q, new_k, new_v, k_pool, v_pool, pos, page_table,
-                           active), kw, nb)
-
-
 # --------------------------------------------------------------------- #
 # fused masked adam over pytrees
 # --------------------------------------------------------------------- #
@@ -295,19 +155,10 @@ def _to_2d(a):
 
 
 def masked_adam_tree(params: Pytree, grads: Pytree, mu: Pytree, nu: Pytree,
-                     masks: Pytree, **kw):
+                     masks: Pytree, *, lr, b1=0.9, b2=0.999, eps=1e-8,
+                     weight_decay=0.0, count=0, tau=0.0, use_tau=False,
+                     interpret=False):
     """Fused masked-Adam across every leaf.  Returns (params, mu, nu)."""
-    if _PROFILER is None:
-        return _masked_adam_tree_impl(params, grads, mu, nu, masks, **kw)
-    # params/mu/nu read + written, grads read once
-    nb = 2 * _tree_nbytes(params, mu, nu) + _tree_nbytes(grads)
-    return _profiled_call("masked_adam", _masked_adam_tree_impl,
-                          (params, grads, mu, nu, masks), kw, nb)
-
-
-def _masked_adam_tree_impl(params, grads, mu, nu, masks, *, lr, b1=0.9,
-                           b2=0.999, eps=1e-8, weight_decay=0.0, count=0,
-                           tau=0.0, use_tau=False, interpret=False):
     cf = jnp.asarray(count, jnp.float32) + 1.0
     scal = jnp.stack([
         jnp.asarray(lr, jnp.float32), jnp.asarray(b1, jnp.float32),
@@ -345,7 +196,9 @@ def _to_q8_view(a):
 
 def masked_adam_q8_tree(params: Pytree, grads: Pytree, mu_q: Pytree,
                         mu_scale: Pytree, nu_q: Pytree, nu_scale: Pytree,
-                        masks: Pytree, **kw):
+                        masks: Pytree, *, lr, b1=0.9, b2=0.999, eps=1e-8,
+                        weight_decay=0.0, count=0, tau=0.0, use_tau=False,
+                        interpret=False):
     """Fused dequant->masked-Adam->requant across every leaf.
 
     Moments stay in their quantized storage layout (int8 [NB, BLOCK] +
@@ -353,20 +206,6 @@ def masked_adam_q8_tree(params: Pytree, grads: Pytree, mu_q: Pytree,
     is ever materialized.  Returns
     ``(params', mu_q', mu_scale', nu_q', nu_scale')``.
     """
-    if _PROFILER is None:
-        return _masked_adam_q8_tree_impl(params, grads, mu_q, mu_scale,
-                                         nu_q, nu_scale, masks, **kw)
-    nb = (2 * _tree_nbytes(params, mu_q, mu_scale, nu_q, nu_scale)
-          + _tree_nbytes(grads))
-    return _profiled_call(
-        "masked_adam_q8", _masked_adam_q8_tree_impl,
-        (params, grads, mu_q, mu_scale, nu_q, nu_scale, masks), kw, nb)
-
-
-def _masked_adam_q8_tree_impl(params, grads, mu_q, mu_scale, nu_q,
-                              nu_scale, masks, *, lr, b1=0.9, b2=0.999,
-                              eps=1e-8, weight_decay=0.0, count=0,
-                              tau=0.0, use_tau=False, interpret=False):
     cf = jnp.asarray(count, jnp.float32) + 1.0
     scal = jnp.stack([
         jnp.asarray(lr, jnp.float32), jnp.asarray(b1, jnp.float32),
@@ -435,8 +274,6 @@ def scatter_swap(full, idx, rows, *, mode: str = "auto",
                  donate: bool = False):
     """Swap rows ``idx`` of an arbitrary-rank leaf with ``rows``.
 
-    (Profiling-gated: see ``enable_kernel_profiling``.)
-
     ``full`` [G, ...]; ``rows`` [K, ...] with matching trailing dims.
     Returns ``(new_full, displaced_rows)`` — an exact involution (see
     kernels/scatter_apply.py).  ``mode``: ``pallas`` | ``interpret`` |
@@ -449,16 +286,6 @@ def scatter_swap(full, idx, rows, *, mode: str = "auto",
     """
     if idx.shape[0] == 0:
         return full, rows
-    if _PROFILER is not None:
-        # rows read + written in both directions (swap is an involution)
-        return _profiled_call("scatter_swap", _scatter_swap_impl,
-                              (full, idx, rows),
-                              dict(mode=mode, donate=donate),
-                              2 * rows.nbytes)
-    return _scatter_swap_impl(full, idx, rows, mode=mode, donate=donate)
-
-
-def _scatter_swap_impl(full, idx, rows, *, mode, donate):
     if mode == "auto":
         mode = "pallas" if pallas_available() else "xla"
     if mode == "xla":
@@ -511,10 +338,5 @@ _rglru_scan_op.defvjp(_rg_fwd, _rg_bwd)
 
 
 def rglru_scan(a, b, h0, interpret=False):
-    """Public entry: the custom_vjp scan behind the profiling gate."""
-    if _PROFILER is None:
-        return _rglru_scan_op(a, b, h0, interpret)
-    # a/b read, y written (a-shaped), h0/hN negligible
-    nb = 2 * a.nbytes + b.nbytes
-    return _profiled_call("rglru_scan", _rglru_scan_op,
-                          (a, b, h0, interpret), {}, nb)
+    """Public entry: the custom_vjp scan."""
+    return _rglru_scan_op(a, b, h0, interpret)

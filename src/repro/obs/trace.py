@@ -14,15 +14,25 @@ Design constraints (from the serving hot path):
   request's queue wait ends at admission): ``add_span`` records an
   explicit ``[t0, t1]`` interval without touching the nesting stack.
 
+- **On the profiler's clock too.**  Once JAX is loaded, every span
+  opened with ``span`` also holds a ``jax.profiler.TraceAnnotation`` of
+  its name, so a profiler session (``jax.profiler.start_trace``) gets the
+  program's spans in its own host plane, beside the device's ops.
+  Without a session the annotation costs one native call.  This module
+  never imports JAX itself.
+
 Disabled tracing is ``tracer=None`` at the call site — instrumented code
 guards every emission with one ``is None`` check, which is the entire
 tracer-off cost.  There is deliberately no NullTracer object on the hot
 paths: an attribute load + method call per event would already be most
-of a no-op tracer's budget.
+of a no-op tracer's budget.  ``maybe_span`` is that check for a ``with``
+block.
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
+import sys
 import threading
 import time
 from dataclasses import dataclass, field
@@ -46,11 +56,17 @@ class TraceEvent:
         return 0 if self.t1_ns is None else self.t1_ns - self.t0_ns
 
 
+def _profiler_annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` of ``name`` if JAX is loaded."""
+    jax = sys.modules.get("jax")
+    return None if jax is None else jax.profiler.TraceAnnotation(name)
+
+
 class _OpenSpan:
     """Context-manager handle returned by ``Tracer.span``."""
 
     __slots__ = ("_tr", "name", "lane", "args", "span_id", "parent_id",
-                 "t0_ns")
+                 "t0_ns", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, lane: str, args: dict):
         self._tr = tr
@@ -60,16 +76,22 @@ class _OpenSpan:
         self.span_id = next(tr._ids)
         self.parent_id = None
         self.t0_ns = 0
+        self._ann = None
 
     def __enter__(self) -> "_OpenSpan":
         stack = self._tr._stack()
         self.parent_id = stack[-1].span_id if stack else None
         stack.append(self)
+        self._ann = _profiler_annotation(self.name)
+        if self._ann is not None:
+            self._ann.__enter__()
         self.t0_ns = time.monotonic_ns()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = time.monotonic_ns()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         stack = self._tr._stack()
         # tolerate mis-nested exits: pop to (and including) this span
         while stack:
@@ -155,3 +177,12 @@ class Tracer:
     def __len__(self) -> int:
         with self._lock:
             return len(self._events)
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def maybe_span(tracer: Optional[Tracer], name: str, **kw):
+    """``tracer.span(name, **kw)``, or a do-nothing context when
+    ``tracer`` is None."""
+    return _NO_SPAN if tracer is None else tracer.span(name, **kw)

@@ -22,13 +22,16 @@ replacement.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from dataclasses import dataclass
 from pathlib import Path
 from typing import Any, Callable, Dict, Optional
 
+import jax
+
 from repro.checkpoint import checkpointer as ckpt_lib
-from repro.obs import StepEmitter
+from repro.obs import StepEmitter, maybe_span
 from repro.runtime.straggler import StragglerConfig, StragglerMonitor
 from repro.trainers.api import TrainState, jsonable
 
@@ -122,9 +125,11 @@ def run(trainer, batch_fn: Callable[[int], dict], cfg: TrainLoopConfig,
 
     TraceKit: pass ``tracer``/``metrics`` (``repro.obs``) and every step
     lands as spans on per-stage lanes (``data``, ``step``, ``ckpt``,
-    ``export``) plus structured per-step metrics via a ``StepEmitter``
-    (stdout stays the ``step N: loss=…`` line at ``log_every``).  An
-    explicit ``emitter`` overrides the default-constructed one.
+    ``export``; a BlockLLM core adds its step's phases on ``step``; each
+    compile lands on ``compile``) plus structured per-step metrics via a
+    ``StepEmitter`` (stdout stays the ``step N: loss=…`` line at
+    ``log_every``).  An explicit ``emitter`` overrides the
+    default-constructed one.
     """
     start_step = 0
     if cfg.ckpt_dir:
@@ -139,40 +144,74 @@ def run(trainer, batch_fn: Callable[[int], dict], cfg: TrainLoopConfig,
     export = _AdapterExporter.maybe(trainer, cfg, start_step, emitter=emit)
     mon = StragglerMonitor(cfg.straggler)
     history = []
-    for step in range(start_step, cfg.total_steps):
-        mon.step_begin()
-        if tracer is None:
-            batch = batch_fn(step)
-            metrics_d = trainer.train_step(batch)
-        else:
-            with tracer.span("data", lane="data", step=step + 1):
+    with _traced(trainer, tracer):
+        for step in range(start_step, cfg.total_steps):
+            mon.step_begin()
+            with maybe_span(tracer, "data", lane="data", step=step + 1):
                 batch = batch_fn(step)
-            with tracer.span("train_step", lane="step", step=step + 1):
+            with maybe_span(tracer, "train_step", lane="step",
+                            step=step + 1):
                 metrics_d = trainer.train_step(batch)
-        action = mon.step_end()
-        metrics_d["straggler_action"] = action
-        history.append(metrics_d["loss"])
-        if on_step:
-            on_step(step, metrics_d)
-        emit.on_step(step + 1, metrics_d)
-        if cfg.ckpt_dir and (step + 1) % cfg.ckpt_every == 0:
-            if tracer is None:
-                _save_ckpt(trainer, cfg, step + 1)
-            else:
-                with tracer.span("checkpoint", lane="ckpt", step=step + 1):
+            action = mon.step_end()
+            metrics_d["straggler_action"] = action
+            history.append(metrics_d["loss"])
+            if on_step:
+                on_step(step, metrics_d)
+            emit.on_step(step + 1, metrics_d)
+            if cfg.ckpt_dir and (step + 1) % cfg.ckpt_every == 0:
+                with maybe_span(tracer, "checkpoint", lane="ckpt",
+                                step=step + 1):
                     _save_ckpt(trainer, cfg, step + 1)
-            if export:
-                if tracer is None:
-                    export.emit(trainer, step + 1)
-                else:
-                    with tracer.span("adapter_export", lane="export",
-                                     step=step + 1):
+                if export:
+                    with maybe_span(tracer, "adapter_export", lane="export",
+                                    step=step + 1):
                         export.emit(trainer, step + 1)
-        if crash_at is not None and step + 1 == crash_at:
-            raise RuntimeError(f"simulated node failure at step {step + 1}")
+            if crash_at is not None and step + 1 == crash_at:
+                raise RuntimeError(
+                    f"simulated node failure at step {step + 1}")
     if export:
         export.emit(trainer, cfg.total_steps)
     return {"losses": history, "final_step": cfg.total_steps}
+
+
+# JAX's duration events of a backend compile (a persistent-cache hit
+# included) and of a persistent-cache retrieval, by the ``event`` they
+# take in the ``compile`` spans
+_COMPILE_EVENTS = {
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval",
+}
+
+
+@contextlib.contextmanager
+def _traced(trainer, tracer):
+    """While the loop runs with a tracer: the trainer's core (if it has a
+    ``tracer`` attribute) splits its step into spans on it, and every
+    compile lands as a retroactive ``compile`` span on lane ``compile``,
+    ending when JAX reports it."""
+    if tracer is None:
+        yield
+        return
+
+    def on_duration(event, secs, **kw):
+        kind = _COMPILE_EVENTS.get(event)
+        if kind is not None:
+            t1 = tracer.now()
+            tracer.add_span("compile", t1 - round(secs * 1e9), t1,
+                            lane="compile", event=kind, **kw)
+
+    core = getattr(trainer, "core", None)
+    if not hasattr(core, "tracer"):
+        core = None
+    if core is not None:
+        core.tracer = tracer
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        yield
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+        if core is not None:
+            core.tracer = None
 
 
 def _merged(trainer):

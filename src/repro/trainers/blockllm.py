@@ -30,6 +30,7 @@ from repro.core import units as units_lib
 from repro.core.selection import NormTracker, SelectorConfig, VisitTracker
 from repro.core.units import Plan, PlanStructure
 from repro.models import model as model_lib
+from repro.obs import maybe_span
 from repro.optim.adam import Adam, AdamState
 from repro.trainers.api import (HISTORY_CAP, Lowerable, StateSpec,
                                 TrainerCore, TrainState, nbytes)
@@ -108,6 +109,9 @@ class BlockLLMCore(TrainerCore):
         self._step_fns: Dict = {}
         self._index = None
         self.recompiles = 0
+        # a repro.obs Tracer splits ``step`` into spans; the train loop
+        # sets it for the run it traces
+        self.tracer = None
 
     # ------------------------------------------------------------------ #
     # state plumbing
@@ -244,61 +248,74 @@ class BlockLLMCore(TrainerCore):
         return fn
 
     def step(self, state: TrainState, batch):
+        tr = self.tracer
         arrays, meta = state.arrays, state.meta
-        params = arrays["params"]
-        self.index_for(params)
-        plan = self.plan_of(state)
-        norms, visits = self._trackers(meta)
-        refresh = bool(meta["needs_mask_refresh"])
-        with_masks = arrays["masks"] is not None
+        with maybe_span(tr, "plan", lane="step"):
+            params = arrays["params"]
+            self.index_for(params)
+            plan = self.plan_of(state)
+            norms, visits = self._trackers(meta)
+            refresh = bool(meta["needs_mask_refresh"])
+            with_masks = arrays["masks"] is not None
 
-        fn = self._get_step_fn(plan.structure, refresh, with_masks)
-        sel, opt, masks, loss, dev_metrics, norm_out = fn(
-            params, arrays["sel"], arrays["probe"], plan.stack_idx,
-            plan.probe_idx, arrays["opt"],
-            arrays["masks"] if with_masks
-            else _ones_masks_like(arrays["sel"]),
-            batch, jnp.asarray(meta["q"], jnp.float32))
-        # fresh probe dict: probe rotation mutates it, and the input
-        # state's arrays must stay intact (probe is not donated)
-        active = {"sel": sel, "probe": dict(arrays["probe"])}
-        if not with_masks:
-            masks = None
+        with maybe_span(tr, "dispatch", lane="step"):
+            fn = self._get_step_fn(plan.structure, refresh, with_masks)
+            sel, opt, masks, loss, dev_metrics, norm_out = fn(
+                params, arrays["sel"], arrays["probe"], plan.stack_idx,
+                plan.probe_idx, arrays["opt"],
+                arrays["masks"] if with_masks
+                else _ones_masks_like(arrays["sel"]),
+                batch, jnp.asarray(meta["q"], jnp.float32))
+        if tr is not None:
+            # the host's first read below waits for the device anyway;
+            # this wait only gives it a span of its own
+            with tr.span("device_wait", lane="step"):
+                jax.block_until_ready((loss, norm_out))
 
-        step_no = int(meta["step"])
-        self._ingest_norms(norm_out, plan, params, active, norms, step_no)
-        loss_f = float(loss)
-        loss_history = list(meta["loss_history"]) + [loss_f]
-        step_no += 1
+        with maybe_span(tr, "ingest_norms", lane="step"):
+            # fresh probe dict: probe rotation mutates it, and the input
+            # state's arrays must stay intact (probe is not donated)
+            active = {"sel": sel, "probe": dict(arrays["probe"])}
+            if not with_masks:
+                masks = None
 
-        new_state = self._pack(
-            params, active, opt, masks, plan, meta["q"], norms=norms,
-            visits=visits, step=step_no, loss_history=loss_history,
-            reselections=int(meta["reselections"]),
-            needs_mask_refresh=False,
-            sel_churn=float(meta["sel_churn"]),
-            last_reselect_step=int(meta["last_reselect_step"]))
+            step_no = int(meta["step"])
+            self._ingest_norms(norm_out, plan, params, active, norms,
+                               step_no)
+            loss_f = float(loss)
+            loss_history = list(meta["loss_history"]) + [loss_f]
+            step_no += 1
+
+            new_state = self._pack(
+                params, active, opt, masks, plan, meta["q"], norms=norms,
+                visits=visits, step=step_no, loss_history=loss_history,
+                reselections=int(meta["reselections"]),
+                needs_mask_refresh=False,
+                sel_churn=float(meta["sel_churn"]),
+                last_reselect_step=int(meta["last_reselect_step"]))
 
         every = self.bcfg.selector.reselect_every
-        if every and step_no % every == 0:
-            new_state = self.reselect(new_state)
-        elif not every and sel_lib.should_reselect(
-                loss_history, self.bcfg.selector.patience):
-            new_state = self.reselect(new_state)
+        if (every and step_no % every == 0) or (
+                not every and sel_lib.should_reselect(
+                    loss_history, self.bcfg.selector.patience)):
+            with maybe_span(tr, "reselect", lane="step"):
+                new_state = self.reselect(new_state)
 
-        nm = new_state.meta
-        metrics = {"loss": loss_f, "step": step_no,
-                   "reselections": int(nm["reselections"]),
-                   # selection telemetry (TraceKit / ISSUE 6): fraction
-                   # selected, churn of the latest reselection, gradient
-                   # energy share of the top (1-s) units, cadence
-                   "sel_q": float(nm["q"]),
-                   "sel_churn": float(nm["sel_churn"]),
-                   "sel_grad_concentration": sel_lib.norm_concentration(
-                       norms.norms, 1.0 - self.bcfg.selector.sparsity),
-                   "sel_steps_since_reselect": step_no - int(
-                       nm["last_reselect_step"])}
-        metrics.update({k: float(v) for k, v in dev_metrics.items()})
+        with maybe_span(tr, "step_metrics", lane="step"):
+            nm = new_state.meta
+            metrics = {"loss": loss_f, "step": step_no,
+                       "reselections": int(nm["reselections"]),
+                       # selection telemetry (TraceKit): fraction
+                       # selected, churn of the latest reselection,
+                       # gradient energy share of the top (1-s) units,
+                       # cadence
+                       "sel_q": float(nm["q"]),
+                       "sel_churn": float(nm["sel_churn"]),
+                       "sel_grad_concentration": sel_lib.norm_concentration(
+                           norms.norms, 1.0 - self.bcfg.selector.sparsity),
+                       "sel_steps_since_reselect": step_no - int(
+                           nm["last_reselect_step"])}
+            metrics.update({k: float(v) for k, v in dev_metrics.items()})
         return new_state, metrics
 
     def reselect(self, state: TrainState) -> TrainState:

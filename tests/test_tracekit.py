@@ -2,8 +2,9 @@
 validity/round-trip, metric instrument semantics, the StepEmitter stdout
 contract, the disabled-tracer overhead bound, serve-side bit-identical
 token streams tracer on vs off, the compile-skipping ms_per_step EMA,
-nested stats() sections + deprecated flat aliases, the opt-in kernel
-profiler, and the BlockLLM selection-telemetry helpers."""
+nested stats() sections + deprecated flat aliases, the BlockLLM
+selection-telemetry helpers, and the train step's spans (nesting, the
+profiler's host plane, compile spans, bit-identical training)."""
 import io
 import json
 import threading
@@ -324,44 +325,6 @@ def test_disabled_tracer_overhead_bound():
          f"(>1% of a 1ms decode step)")
 
 
-# --------------------------------------------------------- kernel profiler
-
-
-def test_kernel_profiler_records_and_passthrough():
-    from repro.kernels import ops
-
-    q = jax.random.normal(K(1), (1, 128, 2, 16))
-    k = jax.random.normal(K(2), (1, 128, 2, 16))
-    v = jax.random.normal(K(3), (1, 128, 2, 16))
-    ref = ops.flash_attention(q, k, v, interpret=True)   # profiler off
-    tr, reg = Tracer(), MetricsRegistry()
-    prof = ops.enable_kernel_profiling(tracer=tr, metrics=reg)
-    try:
-        out = ops.flash_attention(q, k, v, interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-5, atol=1e-5)
-        assert len(prof.records) == 1
-        rec = prof.records[0]
-        assert rec["op"] == "flash_attention" and rec["ms"] >= 0
-        assert rec["bytes"] == q.nbytes * 2 + k.nbytes + v.nbytes
-        assert reg.counter("kernels/flash_attention_calls").value == 1
-        spans = tr.spans("flash_attention")
-        assert len(spans) == 1 and spans[0].lane == "kernels"
-        # inside jit the op must pass through untimed (tracer leaves)
-        jitted = jax.jit(lambda a, b, c: ops.flash_attention(
-            a, b, c, interpret=True))
-        np.testing.assert_allclose(np.asarray(jitted(q, k, v)),
-                                   np.asarray(ref), rtol=1e-5,
-                                   atol=1e-5)
-        assert len(prof.records) == 1     # no record from traced call
-        summ = prof.summary()
-        assert summ["flash_attention"]["calls"] == 1
-    finally:
-        ops.disable_kernel_profiling()
-    ops.flash_attention(q, k, v, interpret=True)
-    assert len(prof.records) == 1         # disabled: no further records
-
-
 # ------------------------------------------------- selection telemetry
 
 
@@ -393,3 +356,109 @@ def test_norm_concentration():
     with_inf = {"a": float("inf"), "b": 3.0, "c": 4.0}
     assert sel.norm_concentration(with_inf, 1.0) == 1.0
     assert 0.0 < sel.norm_concentration(with_inf, 0.5) < 1.0
+
+
+# --------------------------------------------------- train step spans
+
+STEP_CHILDREN = ("plan", "dispatch", "device_wait", "ingest_norms",
+                 "step_metrics")
+TRAIN_STEPS = 4
+
+
+def _tiny_train(tracer, steps=TRAIN_STEPS):
+    """A BlockLLM fine-tune of a two-layer model through the train loop;
+    returns the losses and the trained rows on the host."""
+    from repro import trainers
+    from repro.runtime.train_loop import TrainLoopConfig, run
+    cfg = ModelConfig(name="tiny-spans", family="dense", num_layers=2,
+                      d_model=32, num_heads=2, num_kv_heads=1, d_ff=64,
+                      vocab_size=64, remat=False)
+    h = trainers.handle("blockllm", cfg, model.init_params(K(0), cfg),
+                        sparsity=0.5, k_frac=0.5, patience=100)
+
+    def batch_fn(step):
+        t = np.arange(2 * 16).reshape(2, 16) * (step + 3) % 64
+        return {"tokens": t.astype(np.int32)}
+
+    out = run(h, batch_fn, TrainLoopConfig(total_steps=steps,
+                                           log_every=10 ** 6),
+              tracer=tracer)
+    assert h.core.tracer is None          # the loop takes its tracer back
+    return out["losses"], jax.tree.map(np.asarray, h.state.arrays["sel"])
+
+
+@pytest.fixture(scope="module")
+def traced_train():
+    tr = Tracer()
+    return tr, _tiny_train(tr)
+
+
+def _step_of(tr, t_ns):
+    """The loop step whose ``train_step`` span holds ``t_ns``."""
+    for s in tr.spans("train_step"):
+        if s.t0_ns <= t_ns <= s.t1_ns:
+            return s.args["step"]
+    return None
+
+
+def test_train_step_children_by_parent_id(traced_train):
+    tr, _ = traced_train
+    steps = tr.spans("train_step")
+    assert [s.args["step"] for s in steps] == list(range(1, TRAIN_STEPS + 1))
+    for s in steps:
+        kids = [e.name for e in tr.spans() if e.parent_id == s.span_id]
+        assert kids == list(STEP_CHILDREN), kids
+        assert all(e.lane == "step" for e in tr.spans()
+                   if e.parent_id == s.span_id)
+    wait = tr.spans("device_wait")
+    assert len(wait) == TRAIN_STEPS
+    for s, w in zip(steps, wait):
+        assert s.t0_ns <= w.t0_ns <= w.t1_ns <= s.t1_ns
+
+
+def test_tracing_does_not_change_training(traced_train):
+    _, (losses, sel_rows) = traced_train
+    losses0, sel_rows0 = _tiny_train(None)
+    assert losses == losses0
+    flat, flat0 = jax.tree.leaves(sel_rows), jax.tree.leaves(sel_rows0)
+    assert len(flat) == len(flat0) > 0
+    for a, b in zip(flat, flat0):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+
+
+def test_compile_spans_mark_the_compiling_steps(traced_train):
+    tr, _ = traced_train
+    comp = tr.spans("compile")
+    assert comp and all(e.lane == "compile" for e in comp)
+    assert {e.args["event"] for e in comp} <= {"backend_compile",
+                                              "cache_retrieval"}
+    steps = {_step_of(tr, e.t1_ns) for e in comp}
+    # step 1 compiles the mask-refresh step, step 2 the steady one; the
+    # repeated steps compile nothing
+    assert {1, 2} <= steps
+    assert not steps & set(range(3, TRAIN_STEPS + 1))
+    # the listener is gone once the loop returned
+    n = len(comp)
+    jax.jit(lambda x: x * 3 + 1)(np.arange(7.0)).block_until_ready()
+    assert len(tr.spans("compile")) == n
+
+
+def test_profiler_host_plane_holds_the_step_spans(tmp_path):
+    from jax.profiler import ProfileData
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _tiny_train(tr, steps=2)
+    finally:
+        jax.profiler.stop_trace()
+    pd = ProfileData.from_file(str(next(tmp_path.rglob("*.xplane.pb"))))
+    host = [ev for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+    names = [ev.name for ev in host]
+    for n in ("data", "train_step") + STEP_CHILDREN:
+        assert names.count(n) == 2, n
+    # each device_wait lies inside a train_step on the profiler's clock
+    steps = [ev for ev in host if ev.name == "train_step"]
+    for w in (ev for ev in host if ev.name == "device_wait"):
+        assert any(s.start_ns <= w.start_ns and w.end_ns <= s.end_ns
+                   for s in steps)
