@@ -1,20 +1,29 @@
-"""Flash attention (forward) — Pallas/TPU, online-softmax blockwise.
+"""Flash attention, forward and backward, on the splash kernels that ship
+with JAX (``jax.experimental.pallas.ops.tpu.splash_attention``).
 
-Grid (B, H, nq, nk) with the kv dimension innermost ("arbitrary"
-semantics): the f32 accumulator/max/denominator live in VMEM scratch and
-persist across the nk sweep for one (b, h, q-block).  Causal + sliding
-window masks come from block offsets; fully-masked blocks are skipped via
-``pl.when`` (no MXU work issued).  GQA is handled in the k/v index_map
-(h -> h // group) — the repeated heads are never materialized.
+The op takes the model's layout, q [B, S, H, hd] and k/v [B, S, KV, hd],
+and runs three Pallas kernels: the forward (online softmax, f32 running
+max and denominator, the log-sum-exp kept for the backward), and the
+backward's dq and dk/dv kernels, which recompute each block's
+probabilities from it.  No [S, S] score, probability or dS array reaches
+HBM, and blocks the mask leaves empty (above the causal diagonal, outside
+a window) are skipped by the kernels' block-sparse schedule.  The score
+dot takes the inputs' dtype on the MXU and accumulates in f32; splash's
+forward multiplies the f32 probabilities by v in f32.
 
-Block sizes default to (512 q x 512 k) x head_dim tiles: q/k/v/o tiles at
-hd=128 are 512*128*2B = 128 KiB each, accumulator 256 KiB — comfortably
-inside the ~16 MiB VMEM with double buffering.
+GQA: splash's MHA kernel maps q head h to kv head h // (H / KV) in its
+index maps, the grouping ``jnp.repeat`` of ``layers._repeat_kv`` gives,
+so repeated k/v are never materialised; dk/dv sum over each group.
+Splash does not scale the logits: q is scaled by 1/sqrt(hd) here.
+The batch axis is a ``vmap``, which Pallas turns into one more grid axis.
 
-The backward pass intentionally reuses the XLA chunked-attention path
-(``models.layers.attention_chunked``): it is already flash-structured
-(O(S) memory, recomputes probabilities per block) — see ops.py
-``flash_attention`` custom_vjp.
+Masks are ``layers._mask_bias``'s: causal keeps ``q - k >= 0``; a window
+keeps ``q - k < window`` on the causal side (and, without causality,
+everything with ``q - k < window``).
+
+Block sizes are not a knob: ``block_sizes`` picks them from the sequence
+length (measured on one TPU v5e, PERF.md §6).  A sequence that is not a
+multiple of 128 is not covered (``supports``).
 """
 from __future__ import annotations
 
@@ -22,105 +31,63 @@ import functools
 import math
 
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
+
+LANES = 128
+# Block sizes, forward and backward apart: the largest of each list that
+# divides S.  At S 2048, hd 128, 16 heads over 8 kv heads on one TPU v5e
+# (PERF.md §6) the forward was fastest at 512 x 512 (0.54 ms, 1024
+# kv 0.58) and the dq + dk/dv kernels at 1024 x 1024 (1.21 ms, 512 x 512
+# 1.27); the fused backward kernel was faster but holds an f32 dq per kv
+# block (128 MiB more at that shape).
+_FWD_BLOCKS = (512, 256, 128)
+_BWD_BLOCKS = (1024, 512, 256, 128)
 
 
-def _kernel(q_ref, k_ref, v_ref, o_ref, acc, m_scr, l_scr,
-            *, scale, causal, window, block_q, block_k, nk, sq, sk):
-    ki = pl.program_id(3)
-    qi = pl.program_id(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        acc[...] = jnp.zeros_like(acc)
-        m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
-        l_scr[...] = jnp.zeros_like(l_scr)
-
-    q_start = qi * block_q
-    k_start = ki * block_k
-    # skip blocks that are entirely masked out
-    diag_ok = (not causal) or (k_start <= q_start + block_q - 1)
-    win_ok = (not window) or (q_start - (k_start + block_k - 1) < window)
-
-    @pl.when(jnp.logical_and(diag_ok, win_ok))
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)          # [bq, hd]
-        k = k_ref[0, 0].astype(jnp.float32)          # [bk, hd]
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-        qp = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        kp = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        ok = (qp < sq) & (kp < sk)
-        if causal:
-            ok &= qp >= kp
-        if window:
-            ok &= qp - kp < window
-        s = jnp.where(ok, s, -jnp.inf)
-
-        m_prev = m_scr[...]                          # [bq, 1]
-        m_new = jnp.maximum(m_prev[:, 0], s.max(-1))[:, None]
-        m_safe = jnp.maximum(m_new, -1e30)
-        p = jnp.exp(s - m_safe)
-        corr = jnp.exp(jnp.maximum(m_prev, -1e30) - m_safe)
-        l_scr[...] = l_scr[...] * corr + p.sum(-1)[:, None]
-        acc[...] = acc[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())))
-        m_scr[...] = m_new
-
-    @pl.when(ki == nk - 1)
-    def _finalize():
-        o_ref[0, 0] = (acc[...] / jnp.maximum(l_scr[...], 1e-30)).astype(
-            o_ref.dtype)
+def supports(seq_len: int) -> bool:
+    """Whether the op covers a self-attention of ``seq_len`` tokens."""
+    return seq_len % LANES == 0
 
 
-@functools.partial(
-    jax.jit, static_argnames=("causal", "window", "scale", "block_q",
-                              "block_k", "interpret"))
-def flash_attention_fwd(q, k, v, *, causal=True, window=0, scale=None,
-                        block_q=512, block_k=512, interpret=False):
-    """q [B, Sq, H, hd]; k/v [B, Sk, KV, hd] -> o [B, Sq, H, hd]."""
-    B, Sq, H, hd = q.shape
-    _, Sk, KV, _ = k.shape
-    group = H // KV
-    scale = scale if scale is not None else 1.0 / math.sqrt(hd)
-    block_q = min(block_q, Sq)
-    block_k = min(block_k, Sk)
-    nq = pl.cdiv(Sq, block_q)
-    nk = pl.cdiv(Sk, block_k)
+def block_sizes(seq_len: int) -> splash.BlockSizes:
+    fwd = next(b for b in _FWD_BLOCKS if seq_len % b == 0)
+    bwd = next(b for b in _BWD_BLOCKS if seq_len % b == 0)
+    return splash.BlockSizes(
+        block_q=fwd, block_kv=fwd, block_kv_compute=fwd,
+        block_q_dkv=bwd, block_kv_dkv=bwd, block_kv_dkv_compute=bwd,
+        block_q_dq=bwd, block_kv_dq=bwd)
 
-    # layout [B, H, S, hd] for clean tiling
-    qt = q.swapaxes(1, 2)
-    kt = k.swapaxes(1, 2)
-    vt = v.swapaxes(1, 2)
 
-    kernel = functools.partial(
-        _kernel, scale=scale, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, nk=nk, sq=Sq, sk=Sk)
+def _mask(seq_len: int, causal: bool, window: int):
+    shape = (seq_len, seq_len)
+    if window:
+        # LocalMask keeps q - left <= k <= q + right
+        return splash.LocalMask(shape, (window - 1, 0 if causal else None),
+                                offset=0)
+    return splash.CausalMask(shape) if causal else splash.FullMask(shape)
 
-    out = pl.pallas_call(
-        kernel,
-        grid=(B, H, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, hd),
-                         lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_k, hd),
-                         lambda b, h, i, j, g=group: (b, h // g, j, 0)),
-            pl.BlockSpec((1, 1, block_k, hd),
-                         lambda b, h, i, j, g=group: (b, h // g, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, block_q, hd),
-                               lambda b, h, i, j: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, hd), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=interpret,
-    )(qt, kt, vt)
-    return out.swapaxes(1, 2)
+
+@functools.lru_cache(maxsize=32)
+def _kernel(heads: int, seq_len: int, causal: bool, window: int,
+            interpret: bool):
+    mask = splash.MultiHeadMask([_mask(seq_len, causal, window)] * heads)
+    # the block-sparse schedule is made once, as concrete arrays, and
+    # enters every program that uses it as constants (built under a
+    # trace they would be that trace's tracers)
+    with jax.ensure_compile_time_eval():
+        return splash.make_splash_mha(
+            mask, block_sizes=block_sizes(seq_len), head_shards=1,
+            q_seq_shards=1, interpret=interpret)
+
+
+def flash_attention(q, k, v, *, causal=True, window=0, interpret=False):
+    """q [B, S, H, hd]; k/v [B, S, KV, hd] -> o [B, S, H, hd] in q's dtype."""
+    B, S, H, hd = q.shape
+    if not supports(S) or k.shape[1] != S:
+        raise ValueError(f"flash attention needs a self-attention over a "
+                         f"multiple of {LANES} tokens; got q {q.shape}, "
+                         f"k {k.shape}")
+    kern = _kernel(H, S, bool(causal), int(window), bool(interpret))
+    qt = (q * (1.0 / math.sqrt(hd))).astype(q.dtype).swapaxes(1, 2)
+    o = jax.vmap(kern)(qt, k.swapaxes(1, 2), v.swapaxes(1, 2))
+    return o.swapaxes(1, 2)

@@ -1,7 +1,7 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-- ``flash_attention``: custom_vjp — Pallas forward, XLA flash-structured
-  backward (recomputes block probabilities; O(S) memory).
+- ``flash_attention``: the splash kernels, forward and backward (training
+  attention; ``models/model.py`` takes it in train mode).
 - ``masked_adam_tree``: applies the fused update leaf-wise over a pytree
   (2-D flattening; the kernel grid handles padding).
 - ``rglru_scan``: linear-recurrence kernel with associative-scan VJP.
@@ -33,42 +33,22 @@ def pallas_available() -> bool:
 
 
 # --------------------------------------------------------------------- #
-# flash attention with XLA backward
+# flash attention (training: forward and backward kernels)
 # --------------------------------------------------------------------- #
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_attention_op(q, k, v, causal=True, window=0, interpret=False):
-    return fa.flash_attention_fwd(q, k, v, causal=causal, window=window,
+def flash_attention(q, k, v, causal=True, window=0, interpret=False):
+    """Self-attention q [B, S, H, hd], k/v [B, S, KV, hd] -> [B, S, H, hd]
+    through the splash kernels, forward and backward (see
+    kernels/flash_attention.py); S a multiple of 128
+    (``flash_attention_supports``).  Its device ops carry the scope
+    ``flash_attn``, which ``bench/metrics/attn_ms.train.py`` reads."""
+    with jax.named_scope("flash_attn"):
+        return fa.flash_attention(q, k, v, causal=causal, window=window,
                                   interpret=interpret)
 
 
-def _fa_fwd(q, k, v, causal, window, interpret):
-    o = fa.flash_attention_fwd(q, k, v, causal=causal, window=window,
-                               interpret=interpret)
-    return o, (q, k, v)
-
-
-def _fa_bwd(causal, window, interpret, res, do):
-    q, k, v = res
-    B, Sq = q.shape[:2]
-    pos = jnp.broadcast_to(jnp.arange(Sq, dtype=jnp.int32)[None], (B, Sq))
-
-    def ref(q, k, v):
-        return layers.attention_chunked(q, k, v, pos, pos, causal=causal,
-                                        window=window)
-
-    _, vjp = jax.vjp(ref, q, k, v)
-    return vjp(do)
-
-
-_flash_attention_op.defvjp(_fa_fwd, _fa_bwd)
-
-
-def flash_attention(q, k, v, causal=True, window=0, interpret=False):
-    """Public entry: the custom_vjp op (transparent to autodiff — grad
-    reaches the custom_vjp)."""
-    return _flash_attention_op(q, k, v, causal, window, interpret)
+flash_attention_supports = fa.supports
 
 
 # --------------------------------------------------------------------- #

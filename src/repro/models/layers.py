@@ -135,8 +135,8 @@ def attention_chunked(q, k, v, q_pos, k_pos, *, causal=True, window=0,
                       q_chunk=1024, kv_chunk=1024, softcap=0.0):
     """Blockwise online-softmax attention; O(Sq/qc * qc * kc) live memory.
 
-    Numerically identical (up to fp assoc.) to ``attention_full``; this is
-    the XLA reference of the Pallas flash kernel and the long-context path.
+    Numerically identical (up to fp assoc.) to ``attention_full``; the
+    long-context path where the flash kernel is not taken.
 
     Structure (§Perf iteration 1, see EXPERIMENTS.md): the q loop is a
     *static python loop* so that for q-chunk ``i`` the inner kv scan has

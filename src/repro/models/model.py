@@ -129,6 +129,26 @@ def _attn_cache_len(cfg, btype, seq_len):
     return seq_len
 
 
+def _train_flash(attn_impl, seq_len, cfg) -> bool:
+    """Whether a train-mode self-attention runs as the flash kernel
+    (``kernels.ops.flash_attention``, forward and backward).
+
+    ``"pallas"``/``"pallas_interpret"`` force it; ``"auto"`` takes it on a
+    TPU with no shard rules installed (the distributed launcher keeps XLA
+    attention) and otherwise runs as ``"full"``.  Either way only where
+    the kernel covers the shape and the mask: no logit softcap, S a
+    multiple of 128.  ``"full"`` and ``"chunked"`` never take it.
+    """
+    from repro.kernels import ops as kernel_ops
+    if attn_impl == "auto":
+        if not kernel_ops.pallas_available() or shard_ctx.get() is not None:
+            return False
+    elif attn_impl not in ("pallas", "pallas_interpret"):
+        return False
+    return not cfg.attn_softcap and kernel_ops.flash_attention_supports(
+        seq_len)
+
+
 def _block_apply(cfg, btype, params, x, *, positions, mode, cache,
                  enc_out=None, pos=None, attn_impl="chunked",
                  chunk_start=0, page_table=None, active=None, begin=None):
@@ -382,7 +402,11 @@ def _block_apply(cfg, btype, params, x, *, positions, mode, cache,
                 positions, jnp.concatenate([kp, positions], axis=1),
                 causal=True, window=window, softcap=cfg.attn_softcap)
         else:
-            if attn_impl == "full" or S <= 2048:
+            if mode == "train" and _train_flash(attn_impl, S, cfg):
+                from repro.kernels import ops as kernel_ops
+                o = kernel_ops.flash_attention(
+                    q, k, v, True, window, attn_impl == "pallas_interpret")
+            elif attn_impl in ("full", "auto") or S <= 2048:
                 o = layers.attention_full(
                     q, k, v, positions, positions, causal=True, window=window,
                     softcap=cfg.attn_softcap)

@@ -39,7 +39,7 @@ class BAdamCore(BlockLLMCore):
 
     def __init__(self, cfg, *, switch_every=100, block_rows=1,
                  train_embeddings=False, adam=None, loss_fn=None,
-                 attn_impl="full", bcfg=None, quantize_state=False):
+                 attn_impl="auto", bcfg=None, quantize_state=False):
         super().__init__(
             cfg,
             bcfg=bcfg or badam_config(switch_every, block_rows,
@@ -51,7 +51,7 @@ class BAdamCore(BlockLLMCore):
 @register("badam")
 def make_badam(cfg, *, switch_every=100, block_rows=1,
                train_embeddings=False, adam=None, loss_fn=None,
-               attn_impl="full", quantize_state=False, **_) -> BAdamCore:
+               attn_impl="auto", quantize_state=False, **_) -> BAdamCore:
     return BAdamCore(cfg, switch_every=switch_every, block_rows=block_rows,
                      train_embeddings=train_embeddings, adam=adam,
                      loss_fn=loss_fn, attn_impl=attn_impl,

@@ -90,7 +90,7 @@ class BlockLLMCore(TrainerCore):
     )
 
     def __init__(self, cfg, *, bcfg=None, adam: Optional[Adam] = None,
-                 loss_fn=None, attn_impl: str = "full",
+                 loss_fn=None, attn_impl: str = "auto",
                  quantize_state: bool = False):
         from repro.core.blockllm import BlockLLMConfig
         from repro.optim.q8adam import Q8Adam
@@ -449,7 +449,7 @@ class BlockLLMCore(TrainerCore):
 
 @register("blockllm")
 def make_blockllm(cfg, *, adam=None, bcfg=None, loss_fn=None,
-                  attn_impl="full", sparsity=0.95, patience=100,
+                  attn_impl="auto", sparsity=0.95, patience=100,
                   policy="static", k_frac=0.25, probe_rows=1,
                   quantize_state=False, **_) -> BlockLLMCore:
     if bcfg is None:

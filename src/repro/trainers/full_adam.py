@@ -36,7 +36,7 @@ class FullAdamCore(TrainerCore):
     )
 
     def __init__(self, cfg, *, adam: Optional[Adam] = None, loss_fn=None,
-                 attn_impl: str = "full", quantize_state: bool = False):
+                 attn_impl: str = "auto", quantize_state: bool = False):
         self.cfg = cfg
         self.adam = adam or Adam(lr=1e-3)
         if quantize_state and not isinstance(self.adam, Q8Adam):
@@ -73,7 +73,7 @@ class FullAdamCore(TrainerCore):
 
 
 @register("adam")
-def make_full_adam(cfg, *, adam=None, loss_fn=None, attn_impl="full",
+def make_full_adam(cfg, *, adam=None, loss_fn=None, attn_impl="auto",
                    quantize_state=False, **_) -> FullAdamCore:
     return FullAdamCore(cfg, adam=adam, loss_fn=loss_fn,
                         attn_impl=attn_impl, quantize_state=quantize_state)

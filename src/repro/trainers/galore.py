@@ -29,7 +29,7 @@ class GaLoreCore(TrainerCore):
     )
 
     def __init__(self, cfg, *, galore=None, loss_fn=None,
-                 attn_impl: str = "full"):
+                 attn_impl: str = "auto"):
         from repro.baselines.galore import GaLore
         self.cfg = cfg
         self.galore = galore or GaLore()
@@ -65,7 +65,7 @@ class GaLoreCore(TrainerCore):
 
 
 @register("galore")
-def make_galore(cfg, *, galore=None, loss_fn=None, attn_impl="full",
+def make_galore(cfg, *, galore=None, loss_fn=None, attn_impl="auto",
                 rank=8, lr=1e-3, update_proj_gap=200, **_) -> GaLoreCore:
     if galore is None:
         from repro.baselines.galore import GaLore

@@ -31,7 +31,7 @@ class LoRACore(TrainerCore):
 
     def __init__(self, cfg, *, rank: int = 8, alpha=None,
                  adam: Optional[Adam] = None, loss_fn=None,
-                 attn_impl: str = "full"):
+                 attn_impl: str = "auto"):
         self.cfg = cfg
         self.rank = rank
         self.alpha = alpha if alpha is not None else 4 * rank
@@ -90,6 +90,6 @@ class LoRACore(TrainerCore):
 
 @register("lora")
 def make_lora(cfg, *, rank=8, alpha=None, adam=None, loss_fn=None,
-              attn_impl="full", **_) -> LoRACore:
+              attn_impl="auto", **_) -> LoRACore:
     return LoRACore(cfg, rank=rank, alpha=alpha, adam=adam,
                     loss_fn=loss_fn, attn_impl=attn_impl)

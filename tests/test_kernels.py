@@ -9,7 +9,6 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops
-from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.masked_adam import masked_adam_2d
 from repro.kernels.ref import (flash_attention_ref, masked_adam_ref,
                                rglru_ref)
@@ -62,6 +61,8 @@ def test_masked_adam_tree_wrapper():
 
 
 # ------------------------------------------------------------ flash attn
+# ops.flash_attention: the splash kernels, forward and backward, with
+# block sizes taken from the shape; interpret mode here.
 
 @pytest.mark.parametrize(
     "B,S,H,KV,hd,causal,window",
@@ -74,8 +75,7 @@ def test_flash_attention_sweep(B, S, H, KV, hd, causal, window):
     q = jax.random.normal(K(1), (B, S, H, hd))
     k = jax.random.normal(K(2), (B, S, KV, hd))
     v = jax.random.normal(K(3), (B, S, KV, hd))
-    o = flash_attention_fwd(q, k, v, causal=causal, window=window,
-                            block_q=128, block_k=128, interpret=True)
+    o = ops.flash_attention(q, k, v, causal, window, True)
     r = flash_attention_ref(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(np.asarray(o), np.asarray(r), atol=2e-5)
 
@@ -85,24 +85,39 @@ def test_flash_attention_dtypes(dtype):
     q = jax.random.normal(K(1), (1, 128, 2, 64), dtype)
     k = jax.random.normal(K(2), (1, 128, 2, 64), dtype)
     v = jax.random.normal(K(3), (1, 128, 2, 64), dtype)
-    o = flash_attention_fwd(q, k, v, block_q=64, block_k=64, interpret=True)
+    o = ops.flash_attention(q, k, v, True, 0, True)
+    assert o.dtype == dtype
     r = flash_attention_ref(q, k, v)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(o, np.float32),
                                np.asarray(r, np.float32), atol=tol)
 
 
-def test_flash_attention_grad_matches_ref():
-    q = jax.random.normal(K(1), (1, 128, 4, 32))
-    k = jax.random.normal(K(2), (1, 128, 2, 32))
-    v = jax.random.normal(K(3), (1, 128, 2, 32))
+@pytest.mark.parametrize("causal,window", [(True, 0), (True, 48),
+                                           (False, 0)])
+def test_flash_attention_grad_matches_ref(causal, window):
+    # GQA: 4 q heads over 2 kv heads, so dk/dv sum over each group
+    q = jax.random.normal(K(1), (1, 256, 4, 32))
+    k = jax.random.normal(K(2), (1, 256, 2, 32))
+    v = jax.random.normal(K(3), (1, 256, 2, 32))
 
-    gk = jax.grad(lambda *a: (ops.flash_attention(*a, True, 0, True) ** 2
-                              ).sum(), argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(lambda *a: (flash_attention_ref(*a, causal=True) ** 2
+    gk = jax.grad(lambda *a: (ops.flash_attention(*a, causal, window, True)
+                              ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda *a: (flash_attention_ref(*a, causal=causal,
+                                                  window=window) ** 2
                               ).sum(), argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gk, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
+
+
+def test_flash_attention_refuses_uncovered_lengths():
+    # a sequence off the 128-lane blocks is not covered: the model keeps
+    # XLA attention there (models/model._train_flash)
+    assert ops.flash_attention_supports(256)
+    assert not ops.flash_attention_supports(100)
+    q = jnp.zeros((1, 100, 2, 32))
+    with pytest.raises(ValueError, match="multiple of 128"):
+        ops.flash_attention(q, q, q, True, 0, True)
 
 
 # ------------------------------------------------------------ rglru

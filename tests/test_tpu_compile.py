@@ -4,7 +4,9 @@ Nothing runs: each kernel is lowered and compiled by the TPU compiler for
 a described (not attached) ``v5e:2x2`` topology, which refuses what the
 chip would refuse — block shapes off the (8, 128) tiling, too much VMEM
 — at no chip time.  The widths are llama-350m's (d=1024, 16 heads,
-hd=64, d_ff 2736, vocab 32000; 8 slots, max-seq 1024, 16-row pages).
+hd=64, d_ff 2736, vocab 32000; 8 slots, max-seq 1024, 16-row pages);
+the training flash attention also at the benchmark's InternLM2-1.8B
+shape (2 x 2048 tokens, 16 heads over 8 kv heads, hd=128).
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may hold the TPU library, and every test
@@ -16,6 +18,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import decode_attention as da
+from repro.kernels import flash_attention as fa
 from repro.kernels import masked_adam as ma
 from repro.kernels import scatter_apply as sa
 
@@ -79,6 +82,26 @@ def test_paged_decode_attention_compiles(one_chip, no_persistent_cache,
                        pool, pool, ((B,), jnp.int32), ((B, np_), jnp.int32),
                        ((B,), jnp.bool_))
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("batch,seq,heads,kv_heads,head_dim,window", [
+    (B, C, H, KV, HD, 0),           # llama-350m
+    (2, 2048, 16, 8, 128, 0),       # InternLM2-1.8B, the benchmark's step
+    (1, 2048, 8, 1, 256, 512),      # GQA with one kv head and a window
+])
+def test_flash_attention_fwd_bwd_compiles(one_chip, no_persistent_cache,
+                                          batch, seq, heads, kv_heads,
+                                          head_dim, window):
+    def fwd_bwd(q, k, v):
+        loss = lambda *a: fa.flash_attention(
+            *a, window=window).astype(jnp.float32).sum()
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    kv = ((batch, seq, kv_heads, head_dim), jnp.bfloat16)
+    hlo = _compile_hlo(fwd_bwd, one_chip,
+                       ((batch, seq, heads, head_dim), jnp.bfloat16), kv, kv)
+    # the forward, the dq and the dk/dv kernels
+    assert hlo.count("tpu_custom_call") >= 3
 
 
 @pytest.mark.parametrize("mask_dtype", [jnp.bool_, jnp.float32])
