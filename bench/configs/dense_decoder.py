@@ -57,9 +57,9 @@ def rope(x, pos, theta):
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], -1)
 
 
-def block(x, w, m, rnd=ident):
-    """One decoder layer over full sequences x [B, S, d]; w holds the
-    layer's leaves by name."""
+def attention(x, w, m, rnd=ident):
+    """The attention half of a layer over full sequences x [B, S, d]
+    (its residual included); w holds the layer's leaves by name."""
     B, S, _ = x.shape
     d, H, KV, hd = dims(m)
     eps, theta = m["rms_norm_eps"], m["rope_theta"]
@@ -74,10 +74,20 @@ def block(x, w, m, rnd=ident):
     s = jnp.where(pos[:, None] >= pos[None, :], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhqk,bkhd->bqhd", rnd(p), rnd(v), precision=HI)
-    x = x + mm(o.reshape(B, S, H * hd), w["wo"], rnd)
-    h = rms(x, w["ln2"], eps)
-    return x + mm(jax.nn.silu(mm(h, w["w_gate"], rnd)) * mm(h, w["w_up"], rnd),
-                  w["w_down"], rnd)
+    return x + mm(o.reshape(B, S, H * hd), w["wo"], rnd)
+
+
+def swiglu(h, w_gate, w_up, w_down, rnd=ident):
+    return mm(jax.nn.silu(mm(h, w_gate, rnd)) * mm(h, w_up, rnd), w_down, rnd)
+
+
+def block(x, w, m, rnd=ident):
+    """One decoder layer over full sequences x [B, S, d]; w holds the
+    layer's leaves by name.  Returns the layer's output and its auxiliary
+    loss (none here)."""
+    x = attention(x, w, m, rnd)
+    h = rms(x, w["ln2"], m["rms_norm_eps"])
+    return x + swiglu(h, w["w_gate"], w["w_up"], w["w_down"], rnd), 0.0
 
 
 def logits(x, final_norm, head, m, rnd=ident):

@@ -41,9 +41,10 @@ def _step_gaps(prog, ref):
 def _look(cell, d, ref):
     """Mask elements that differ, and the reference on the program's mask."""
     import numpy as np
-    from bench import train_cell
-    order = np.argsort(d.rows)
-    pm = {n: v if n == "final_norm" else v[order] for n, v in d.mask.items()}
+    from bench import layouts, train_cell
+    tab = layouts.table(cell.config)
+    pm = {p: v[np.argsort(d.rows[tab[p].stack])] if tab[p].stack else v
+          for p, v in d.mask.items()}
     differ = {n: int((np.asarray(ref["mask"][n]) != pm[n]).sum())
               for n in pm}
     kept = {n: int(np.asarray(ref["mask"][n]).sum()) for n in pm}

@@ -1,35 +1,29 @@
 """Operations the algorithm needs, from the configuration's
-shapes alone (published keys of ``bench/configs/<name>.json``).
+shapes alone (published keys of ``bench/configs/<name>.json``, read
+through its layout, ``bench/layouts/<reference>.py``).
 
-- A train step: PaLM's count, ``6 N + 12 L d S`` FLOPs per token, with
-  ``N`` the parameters outside the embedding table (the untied head is a
-  matmul and counts).  BlockLLM prunes the weight gradients of frozen
-  rows; they are counted as if done, so the share compares with full
-  fine-tuning.  Recomputation (remat) is not counted.
+- A train step: ``6 N + A`` FLOPs per token (PaLM's count), with ``N``
+  the parameters a token goes through outside the embedding table (the
+  untied head is a matmul and counts; of a mixture of experts the
+  router, the shared experts and ``num_experts_per_tok`` routed experts,
+  with no capacity padding and no dropped token counted) and ``A`` the
+  attention's scores and weighted sums, forward and backward.  For a
+  dense decoder ``A = 12 L (H hd) S``.  BlockLLM prunes the weight
+  gradients of frozen rows; they are counted as if done, so the share
+  compares with full fine-tuning.  Recomputation (remat) is not counted.
 """
 from __future__ import annotations
 
-import numpy as np
-
-from bench import weights
-
-
-def _sizes(m: dict) -> dict:
-    return {k: int(np.prod(v)) for k, v in weights.shapes(m).items()}
+from bench import layouts
 
 
 def non_embedding_params(m: dict) -> int:
-    s = _sizes(m)
-    layer = sum(s[n] for _, n in weights.LAYER_LEAVES)
-    return m["num_hidden_layers"] * layer + s["final_norm"] + s["head"]
+    """Every parameter outside the embedding table."""
+    return sum(layouts.numel(m, l) for l in layouts.of(m).leaves(m)
+               if l.path != "embed")
 
 
-def _attn_width(m: dict) -> int:
-    H = m["num_attention_heads"]
-    return H * (m.get("head_dim") or m["hidden_size"] // H)
-
-
-def train_flops_per_token(m: dict, seq: int) -> float:
-    return 6.0 * non_embedding_params(m) + 12.0 * m["num_hidden_layers"] * \
-        _attn_width(m) * seq
-
+def train_flops_per_token(m: dict, seq: int) -> int:
+    lay = layouts.of(m)
+    return 6 * lay.active_params_per_token(m) \
+        + lay.attention_flops_per_token(m, seq)

@@ -5,8 +5,10 @@ A cell is an entry of ``workloads`` in ``BENCHMARK.json``.  Its
 configuration file is named there; its traffic mix is
 ``bench/traffic/<traffic>.json``; its limits are
 ``bench/limits/<cell>.json``; each per-layer metric is read by
-``bench/metrics/<metric>.py``.  The mix's ``kind`` names the driver
-(``bench/<kind>_cell.py``).  Nothing here lists cells.
+``bench/metrics/<metric>.py``.  The configuration's ``"reference"``
+names its plain reference (``bench/configs/<reference>.py``) and its
+layout (``bench/layouts/<reference>.py``); the mix's ``kind`` names the
+driver (``bench/<kind>_cell.py``).  Nothing here lists cells.
 """
 from __future__ import annotations
 
@@ -20,6 +22,8 @@ import time
 from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Any, Dict, List, Optional
+
+from bench import layouts
 
 ROOT = Path(__file__).resolve().parents[1]
 RUNS = ROOT / ".bench_runs"          # traces and scratch of runs (ignored)
@@ -43,22 +47,9 @@ class Cell:
     root: Path = ROOT
 
     def model_config(self):
-        """The program's ``ModelConfig`` from the published keys."""
-        from repro.configs.base import ModelConfig
-        c = self.config
-        return ModelConfig(
-            name=c["name"], family="dense",
-            num_layers=int(c["num_hidden_layers"]),
-            d_model=int(c["hidden_size"]),
-            num_heads=int(c["num_attention_heads"]),
-            num_kv_heads=int(c["num_key_value_heads"]),
-            d_ff=int(c["intermediate_size"]),
-            vocab_size=int(c["vocab_size"]),
-            head_dim=int(c.get("head_dim", 0)),
-            rope_theta=float(c["rope_theta"]),
-            norm_eps=float(c["rms_norm_eps"]),
-            tie_embeddings=bool(c["tie_word_embeddings"]),
-            dtype=c["torch_dtype"])
+        """The program's ``ModelConfig``, from the published keys by the
+        configuration's layout (``bench/layouts``)."""
+        return layouts.of(self.config).model_config(self.config)
 
     def end_to_end(self) -> List[dict]:
         return [m for m in self.spec["end_to_end"]
@@ -79,6 +70,7 @@ def find_cell(name: str, *, seed: int, seconds: float, trace: bool,
         raise KeyError(f"no workload {name!r} in BENCHMARK.json")
     cfg_entry = next(c for c in spec["configs"] if c["name"] == wl["config"])
     config = json.loads((root / cfg_entry["file"]).read_text())
+    layouts.check(config)
     traffic = json.loads((root / "bench" / "traffic"
                           / f"{wl['traffic']}.json").read_text())
     lim_path = root / "bench" / "limits" / f"{name}.json"

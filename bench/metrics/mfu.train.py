@@ -1,6 +1,7 @@
 """Train step: model FLOP/s utilisation, in % of the chip's bf16 peak.
-FLOPs per token by ``bench/flops.py`` (6 N + 12 L d S, BlockLLM's pruned
-weight gradients counted as if done) times the window's tokens/s."""
+FLOPs per token by ``bench/flops.py`` (6 N over the parameters a token
+goes through plus the attention's, BlockLLM's pruned weight gradients
+counted as if done) times the window's tokens/s."""
 
 
 def read(ctx):

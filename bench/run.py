@@ -42,11 +42,11 @@ def main(argv=None) -> int:
     if not (ROOT / "src" / "repro").is_dir():
         fail(f"no program sources under {ROOT / 'src'}", 2)
     sys.path[:0] = [str(ROOT), str(ROOT / "src")]
-    from bench import harness
+    from bench import harness, layouts
     try:
         cell = harness.find_cell(args.workload, seed=args.seed,
                                  seconds=args.seconds, trace=bool(args.trace))
-    except (KeyError, FileNotFoundError) as e:
+    except (KeyError, FileNotFoundError, layouts.ConfigError) as e:
         fail(str(e), 2)
 
     t_start = time.perf_counter()

@@ -29,3 +29,20 @@ def test_peaks_known_kind_and_unknown_kind_raises():
         peaks.for_device("TPU v9 imaginary")
     with pytest.raises(KeyError):
         peaks.for_device("cpu")
+
+
+def test_internlm2_train_step_to_the_flop():
+    m = json.loads((Path(__file__).parents[1] / "configs"
+                    / "internlm2-1.8b.json").read_text())
+    assert flops.train_flops_per_token(m, 2048) == 11405438976
+
+
+def test_moe_train_step_by_hand():
+    m = json.loads((Path(__file__).parent / "data" / "tiny-moe.json").read_text())
+    # L=2, d=64, H=4, KV=2, hd=16, E=8, k=2, f=32, one shared expert, V=256
+    attn = 64 + 64 * 64 + 2 * 64 * 32 + 64 * 64 + 64
+    router, expert, shared = 64 * 8, 3 * 64 * 32, 3 * 64 * 32
+    layer_all = attn + router + 8 * expert + shared
+    assert flops.non_embedding_params(m) == 2 * layer_all + 64 + 64 * 256
+    active = 2 * (attn + router + 2 * expert + shared) + 64 + 64 * 256
+    assert flops.train_flops_per_token(m, 32) == 6 * active + 12 * 2 * 64 * 32

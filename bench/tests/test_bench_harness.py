@@ -11,18 +11,18 @@ from pathlib import Path
 
 import pytest
 
-from bench import harness, weights
+from bench import harness, layouts, weights
 
 ROOT = Path(__file__).resolve().parents[2]
 DATA = Path(__file__).parent / "data"
 SEED = 2 ** 31 + 4242
 
 
-def tiny_cell(kind, seconds=1.5, trace=False, limits=None):
-    cfg = json.loads((DATA / "tiny.json").read_text())
+def tiny_cell(kind, seconds=1.5, trace=False, limits=None, config="tiny"):
+    cfg = json.loads((DATA / f"{config}.json").read_text())
     mix = json.loads((DATA / f"tiny-{kind}.json").read_text())
     spec = {"end_to_end": [], "per_layer": []}
-    return harness.Cell(f"tiny.{kind}", spec, {"chips": 1}, cfg, mix,
+    return harness.Cell(f"{config}.{kind}", spec, {"chips": 1}, cfg, mix,
                         limits or {}, SEED, seconds, trace)
 
 
@@ -56,12 +56,16 @@ def test_program_layout_weights_equal_the_references_rows():
     flat, _ = jax.tree_util.tree_flatten_with_path(p)
     by = {"/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path): v
           for path, v in flat}
-    assert set(by) == set(weights.PATHS.values())
-    for name, path in weights.PATHS.items():
-        want = by[path] if name in weights.TOP_LEAVES else by[path][jnp.array([1, 0])]
-        assert bool(jnp.array_equal(weights.start(SEED, m, name, [1, 0]), want)), name
-    assert set(weights.change_norms(SEED, m, [1, 0], {
-        n: weights.start(SEED, m, n, [1, 0]) for n in weights.PATHS}).values()) == {0.0}
+    tab = layouts.table(m)
+    assert set(by) == set(tab)
+    rows = {"s0/pos0": [1, 0]}
+    for path, leaf in tab.items():
+        want = by[path][jnp.array([1, 0])] if leaf.stack else by[path]
+        got = weights.start(SEED, m, path, rows.get(leaf.stack, ()))
+        assert bool(jnp.array_equal(got, want)), path
+    assert set(weights.change_norms(SEED, m, rows, {
+        p: weights.start(SEED, m, p, rows.get(l.stack, ()))
+        for p, l in tab.items()}).values()) == {0.0}
     other = weights.program_params(SEED + 1, m)
     assert not bool(jnp.array_equal(other["head"], p["head"]))
 

@@ -54,14 +54,20 @@ def make_pipeline(cell):
         structure=mix["structure"]))
 
 
-def _leaf_name(path) -> str:
+def _program_path(path) -> str:
+    """The program path of a leaf of the trainer's active tree
+    (``stacks/s0/pos0/attn/wq`` -> ``stages/0/pos0/attn/wq``,
+    ``leaves/final_norm/scale`` -> ``final_norm/scale``)."""
     keys = [str(getattr(k, "key", k)) for k in path]
-    return keys[-2] if keys[-1] == "scale" else keys[-1]
+    if keys[0] == "stacks":
+        si, pos = keys[1].split("/")
+        return "/".join(["stages", si[1:], pos] + keys[2:])
+    return "/".join(keys[1:])
 
 
 def _named(tree):
     flat, _ = jax.tree_util.tree_flatten_with_path(tree)
-    return {_leaf_name(p): v for p, v in flat}
+    return {_program_path(p): v for p, v in flat}
 
 
 @jax.jit
@@ -91,7 +97,8 @@ class Drive:
         self.cell, self.handle, self.t_start = cell, handle, t_start
         self.stack, self.keep_mask = stack, keep_mask
         self.n_check = cell.traffic["check_steps"]
-        self.rows = [int(r) for r in handle.state.meta["stack_idx"]["s0/pos0"]]
+        self.rows = {sid: [int(r) for r in idx]
+                     for sid, idx in handle.state.meta["stack_idx"].items()}
         self.losses, self.gnorms, self.change, self.mask = [], {}, {}, None
         self.setup_s, self.w, self.steps = None, None, 0
 
