@@ -30,6 +30,20 @@ VALID_BLOCKS = {
 
 
 @dataclass(frozen=True)
+class Yarn:
+    """YaRN rope scaling, as DeepSeek-V2's ``rope_scaling`` of type
+    ``yarn`` gives it (``models/layers.yarn_inv_freq`` and
+    ``yarn_softmax_scale`` compute from it)."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     """Static architecture description (frozen => hashable => jit-friendly)."""
 
@@ -60,8 +74,25 @@ class ModelConfig:
     num_experts_per_tok: int = 0
     moe_d_ff: int = 0
     shared_expert_d_ff: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25   # the sharded path's (moe_parallel)
     router_aux_coef: float = 0.01
+    # Expert parallelism: this device holds experts [expert_offset,
+    # expert_offset + num_experts) of the router's ``router_experts``
+    # (0: all of them) and computes their part of the layer.
+    router_experts: int = 0
+    expert_offset: int = 0
+    norm_topk_prob: bool = True      # renormalise the top-k gates
+    routed_scaling_factor: float = 1.0
+    seq_aux: bool = False            # balance loss per sequence (DeepSeek)
+    first_dense_layers: int = 0      # leading layers with a dense MLP
+
+    # Multi-head latent attention (DeepSeek-V2; no q compression): on
+    # when kv_lora_rank > 0.  Train mode only.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_yarn: Optional[Yarn] = None
 
     # Recurrent (RG-LRU)
     lru_width: int = 0
@@ -91,6 +122,14 @@ class ModelConfig:
     def resolved_head_dim(self) -> int:
         return self.head_dim if self.head_dim else self.d_model // self.num_heads
 
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def total_router_experts(self) -> int:
+        return self.router_experts or self.num_experts
+
     def layer_types(self) -> tuple:
         """Per-layer block type, cycling ``pattern`` over ``num_layers``."""
         p = self.pattern
@@ -99,19 +138,36 @@ class ModelConfig:
     def stages(self):
         """Partition the stack into scan stages.
 
-        Returns a list of (pattern, n_groups): full repetitions of the cyclic
-        pattern are scanned together; a trailing remainder (a prefix of the
-        pattern) forms a second stage.  Each stage's params are stacked
+        Returns a list of (pattern, n_groups): the leading dense layers
+        (``first_dense_layers``, whole repetitions of the pattern) form
+        the first stage; full repetitions of the cyclic pattern are
+        scanned together; a trailing remainder (a prefix of the pattern)
+        forms a last stage.  Each stage's params are stacked
         ``[n_groups, ...]`` per pattern position.
         """
         p, L = self.pattern, self.num_layers
-        full, rem = divmod(L, len(p))
         out = []
+        if self.first_dense_layers:
+            n, r = divmod(self.first_dense_layers, len(p))
+            if r or self.first_dense_layers >= L:
+                raise ValueError(
+                    f"first_dense_layers {self.first_dense_layers}: whole "
+                    f"repetitions of the pattern {p}, fewer than {L}")
+            out.append((tuple(p), n))
+            L -= self.first_dense_layers
+        full, rem = divmod(L, len(p))
         if full:
             out.append((tuple(p), full))
         if rem:
             out.append((tuple(p[:rem]), 1))
         return out
+
+    def stage_config(self, si: int) -> "ModelConfig":
+        """The config stage ``si``'s layers are built with: the leading
+        dense stage's has no experts (its MLP is ``d_ff`` wide)."""
+        if self.first_dense_layers and si == 0:
+            return self.replace(num_experts=0, first_dense_layers=0)
+        return self
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
@@ -127,15 +183,21 @@ class ModelConfig:
             n += self.vocab_size * d
         per_layer = {}
         attn = d * H * hd + 2 * d * KV * hd + H * hd * d  # q,k,v,o
+        if self.mla:
+            r, dq = self.kv_lora_rank, self.qk_nope_head_dim + self.qk_rope_head_dim
+            attn = (d * H * dq + d * (r + self.qk_rope_head_dim) + r
+                    + r * H * (self.qk_nope_head_dim + self.v_head_dim)
+                    + H * self.v_head_dim * d)
         if self.mlp_type in ("swiglu", "geglu"):
             mlp = 3 * d * self.d_ff
         elif self.mlp_type == "none":
             mlp = 0
         else:
             mlp = 2 * d * self.d_ff
-        moe = 0
+        moe, dense_mlp = 0, mlp
         if self.num_experts:
-            moe = self.num_experts * 3 * d * self.moe_d_ff + d * self.num_experts
+            moe = (self.num_experts * 3 * d * self.moe_d_ff
+                   + d * self.total_router_experts)
             if self.shared_expert_d_ff:
                 moe += 3 * d * self.shared_expert_d_ff
             mlp = 0
@@ -146,7 +208,10 @@ class ModelConfig:
         mlstm = 4 * d * (2 * d) + 2 * d * d  # up/down proj + qkv-ish, approx
         for i, t in enumerate(self.layer_types()):
             if t in (BLOCK_GLOBAL_ATTN, BLOCK_LOCAL_ATTN):
-                n += attn + (moe if self.num_experts else mlp) + 2 * d
+                if i < self.first_dense_layers:
+                    n += attn + dense_mlp + 2 * d
+                else:
+                    n += attn + (moe if self.num_experts else mlp) + 2 * d
             elif t == BLOCK_RECURRENT:
                 n += rec + mlp + 2 * d
             elif t in (BLOCK_MLSTM, BLOCK_SLSTM):
@@ -171,6 +236,7 @@ def register(cfg: ModelConfig) -> ModelConfig:
 
 _ARCH_MODULES = [
     "qwen2_moe_a2p7b",
+    "deepseek_v2_lite",
     "granite_moe_3b_a800m",
     "deepseek_7b",
     "internlm2_1p8b",

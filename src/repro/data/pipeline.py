@@ -15,6 +15,7 @@ Two sources:
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 from dataclasses import dataclass
 from typing import Iterator, Optional
@@ -41,20 +42,38 @@ def _rng_for(cfg: DataConfig, step: int, row: int) -> np.random.Generator:
     return np.random.default_rng(int.from_bytes(h, "little"))
 
 
+@functools.lru_cache(maxsize=8)
+def _affine_steps(vocab_size: int, seq_len: int):
+    """For j < seq_len: ``31^j`` and ``sum_{i<j} 31^i``, both mod vocab
+    (j steps of x -> 31 x + c take x to ``31^j x + c sum_{i<j} 31^i``)."""
+    mul = np.empty(seq_len, np.int64)
+    acc = np.empty(seq_len, np.int64)
+    m, a = 1, 0
+    for j in range(seq_len):
+        mul[j], acc[j] = m, a
+        m, a = (m * 31) % vocab_size, (a * 31 + 1) % vocab_size
+    return mul, acc
+
+
 def _synthetic_row(cfg: DataConfig, step: int, row: int) -> np.ndarray:
-    """Markov-ish stream: next token = f(prev token, theme) mostly."""
+    """Markov-ish stream: next token = f(prev token, theme) mostly.
+
+    Token t is drawn at random where ``noise[t] < 0.15`` (and at t = 0);
+    elsewhere it is ``(31 prev + 7 theme + 13) % vocab``.  Computed run by
+    run in closed form: token t is the run's first token after
+    ``t - start`` such steps."""
     rng = _rng_for(cfg, step, row)
+    V, S = cfg.vocab_size, cfg.seq_len
     theme = rng.integers(0, cfg.structure)
-    toks = np.empty(cfg.seq_len, np.int32)
-    toks[0] = rng.integers(0, cfg.vocab_size)
-    noise = rng.random(cfg.seq_len)
-    rand = rng.integers(0, cfg.vocab_size, cfg.seq_len)
-    for t in range(1, cfg.seq_len):
-        if noise[t] < 0.15:
-            toks[t] = rand[t]
-        else:  # deterministic successor given (prev, theme)
-            toks[t] = (toks[t - 1] * 31 + theme * 7 + 13) % cfg.vocab_size
-    return toks
+    first = rng.integers(0, V)
+    noise = rng.random(S)
+    rand = rng.integers(0, V, S)
+    t = np.arange(S)
+    start = np.maximum.accumulate(np.where((noise < 0.15) | (t == 0), t, 0))
+    head = np.where(start == 0, first, rand[start])
+    mul, acc = _affine_steps(V, S)
+    c = (int(theme) * 7 + 13) % V
+    return ((mul[t - start] * head + c * acc[t - start]) % V).astype(np.int32)
 
 
 class TokenPipeline:

@@ -14,7 +14,9 @@ forward multiplies the f32 probabilities by v in f32.
 GQA: splash's MHA kernel maps q head h to kv head h // (H / KV) in its
 index maps, the grouping ``jnp.repeat`` of ``layers._repeat_kv`` gives,
 so repeated k/v are never materialised; dk/dv sum over each group.
-Splash does not scale the logits: q is scaled by 1/sqrt(hd) here.
+Splash does not scale the logits: q is scaled here, by 1/sqrt(hd) or
+the given ``scale``.  v may have a head size of its own (MLA: qk 192,
+v 128); splash sizes its forward, dq and dk/dv blocks by each apart.
 The batch axis is a ``vmap``, which Pallas turns into one more grid axis.
 
 Masks are ``layers._mask_bias``'s: causal keeps ``q - k >= 0``; a window
@@ -80,14 +82,18 @@ def _kernel(heads: int, seq_len: int, causal: bool, window: int,
             q_seq_shards=1, interpret=interpret)
 
 
-def flash_attention(q, k, v, *, causal=True, window=0, interpret=False):
-    """q [B, S, H, hd]; k/v [B, S, KV, hd] -> o [B, S, H, hd] in q's dtype."""
+def flash_attention(q, k, v, *, causal=True, window=0, interpret=False,
+                    scale=None):
+    """q [B, S, H, hd]; k [B, S, KV, hd]; v [B, S, KV, hd_v] -> o [B, S,
+    H, hd_v] in q's dtype; scores scaled by ``scale`` (default
+    1/sqrt(hd))."""
     B, S, H, hd = q.shape
     if not supports(S) or k.shape[1] != S:
         raise ValueError(f"flash attention needs a self-attention over a "
                          f"multiple of {LANES} tokens; got q {q.shape}, "
                          f"k {k.shape}")
     kern = _kernel(H, S, bool(causal), int(window), bool(interpret))
-    qt = (q * (1.0 / math.sqrt(hd))).astype(q.dtype).swapaxes(1, 2)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
+    qt = (q * scale).astype(q.dtype).swapaxes(1, 2)
     o = jax.vmap(kern)(qt, k.swapaxes(1, 2), v.swapaxes(1, 2))
     return o.swapaxes(1, 2)

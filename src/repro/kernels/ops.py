@@ -37,15 +37,17 @@ def pallas_available() -> bool:
 # --------------------------------------------------------------------- #
 
 
-def flash_attention(q, k, v, causal=True, window=0, interpret=False):
-    """Self-attention q [B, S, H, hd], k/v [B, S, KV, hd] -> [B, S, H, hd]
-    through the splash kernels, forward and backward (see
-    kernels/flash_attention.py); S a multiple of 128
-    (``flash_attention_supports``).  Its device ops carry the scope
-    ``flash_attn``, which ``bench/metrics/attn_ms.train.py`` reads."""
+def flash_attention(q, k, v, causal=True, window=0, interpret=False,
+                    scale=None):
+    """Self-attention q [B, S, H, hd], k [B, S, KV, hd], v [B, S, KV,
+    hd_v] -> [B, S, H, hd_v] through the splash kernels, forward and
+    backward (see kernels/flash_attention.py); S a multiple of 128
+    (``flash_attention_supports``); scores scaled by ``scale`` (default
+    1/sqrt(hd)).  Its device ops carry the scope ``flash_attn``, which
+    ``bench/metrics/attn_ms.train.py`` reads."""
     with jax.named_scope("flash_attn"):
         return fa.flash_attention(q, k, v, causal=causal, window=window,
-                                  interpret=interpret)
+                                  interpret=interpret, scale=scale)
 
 
 flash_attention_supports = fa.supports

@@ -54,14 +54,51 @@ def rope_freqs(head_dim, theta):
     return 1.0 / (theta ** exponent)  # [hd/2]
 
 
-def apply_rope(x, positions, theta):
-    """x: [..., S, n, hd]; positions: [..., S] int32."""
+def yarn_mscale(factor, mscale=1.0):
+    """DeepSeek-V2's ``yarn_get_mscale``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_range(dim, theta, yarn):
+    """(low, high): the rotary pairs below ``low`` keep their frequency,
+    those above ``high`` are interpolated by the whole factor; DeepSeek's
+    ``yarn_find_correction_range`` with ``beta_fast`` and ``beta_slow``
+    rotations over ``original_max_position``."""
+    def dim_of(rotations):
+        return dim * math.log(yarn.original_max_position
+                              / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+    return (max(math.floor(dim_of(yarn.beta_fast)), 0),
+            min(math.ceil(dim_of(yarn.beta_slow)), dim - 1))
+
+
+def yarn_inv_freq(dim, theta, yarn):
+    """[dim/2] YaRN frequencies: ``theta^(-2i/dim) * ((1 - r_i) + r_i /
+    factor)`` with ``r_i`` the linear ramp from ``low`` to ``high``."""
+    low, high = yarn_range(dim, theta, yarn)
+    i = jnp.arange(dim // 2, dtype=jnp.float32)
+    ramp = jnp.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return rope_freqs(dim, theta) * ((1.0 - ramp) + ramp / yarn.factor)
+
+
+def yarn_softmax_scale(qk_head_dim, yarn):
+    """``qk_head_dim^-1/2`` times ``mscale(factor, mscale_all_dim)^2``."""
+    m = yarn_mscale(yarn.factor, yarn.mscale_all_dim) if yarn.mscale_all_dim \
+        else 1.0
+    return qk_head_dim ** -0.5 * m * m
+
+
+def apply_rope(x, positions, theta, inv_freq=None, mscale=1.0):
+    """x: [..., S, n, hd]; positions: [..., S] int32.  ``inv_freq``
+    [hd/2] replaces theta's frequencies (YaRN); ``mscale`` scales cos and
+    sin."""
     if not theta:
         return x
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)  # [hd/2]
+    freqs = rope_freqs(hd, theta) if inv_freq is None else inv_freq
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., S, hd/2]
     sin, cos = jnp.sin(angles), jnp.cos(angles)
+    if mscale != 1.0:
+        sin, cos = sin * mscale, cos * mscale
     sin = sin[..., None, :]  # broadcast over heads
     cos = cos[..., None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
@@ -98,6 +135,23 @@ def attention_init(key, cfg, *, cross=False):
     }
 
 
+def mla_init(key, cfg):
+    """Multi-head latent attention, no q compression: ``wq`` [d, H (dn +
+    dr)], ``wkv_a`` [d, r + dr] (the latent and the one shared rope key),
+    the latent's norm, ``wkv_b`` [r, H (dn + dv)], ``wo`` [H dv, d]."""
+    d, H, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 4)
+    out_scale = 1.0 / math.sqrt(H * dv) / math.sqrt(2 * cfg.num_layers)
+    return {
+        "wq": dense_init(ks[0], d, H * (dn + dr)),
+        "wkv_a": dense_init(ks[1], d, r + dr),
+        "kv_norm": norm_init(r),
+        "wkv_b": dense_init(ks[2], r, H * (dn + dv)),
+        "wo": dense_init(ks[3], H * dv, d, scale=out_scale),
+    }
+
+
 def _repeat_kv(k, n_rep):
     if n_rep == 1:
         return k
@@ -116,14 +170,16 @@ def _mask_bias(q_pos, k_pos, *, causal, window):
     return jnp.where(ok, 0.0, -1e30).astype(jnp.float32)
 
 
-def attention_full(q, k, v, q_pos, k_pos, *, causal=True, window=0, softcap=0.0):
-    """q [B,Sq,H,hd] k/v [B,Sk,KV,hd] -> [B,Sq,H,hd]."""
+def attention_full(q, k, v, q_pos, k_pos, *, causal=True, window=0, softcap=0.0,
+                   scale=None):
+    """q [B,Sq,H,hd] k [B,Sk,KV,hd] v [B,Sk,KV,hd_v] -> [B,Sq,H,hd_v];
+    the scores scaled by ``scale`` (default 1/sqrt(hd))."""
     B, Sq, H, hd = q.shape
     KV = k.shape[2]
     k = _repeat_kv(k, H // KV)
     v = _repeat_kv(v, H // KV)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
-    logits *= 1.0 / math.sqrt(hd)
+    logits *= 1.0 / math.sqrt(hd) if scale is None else scale
     if softcap:
         logits = jnp.tanh(logits / softcap) * softcap
     logits = logits + _mask_bias(q_pos, k_pos, causal=causal, window=window)[:, None]

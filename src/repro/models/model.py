@@ -47,7 +47,8 @@ def _block_init(key, cfg: ModelConfig, btype: str, *, cross=False):
     if btype in ATTN_BLOCKS:
         p = {
             "ln1": layers.norm_init(cfg.d_model),
-            "attn": layers.attention_init(ks[0], cfg),
+            "attn": (layers.mla_init(ks[0], cfg) if cfg.mla
+                     else layers.attention_init(ks[0], cfg)),
             "ln2": layers.norm_init(cfg.d_model),
         }
         if cfg.num_experts:
@@ -89,8 +90,8 @@ def init_params(key, cfg: ModelConfig, dtype=jnp.float32) -> Pytree:
         "embed": jax.random.normal(ks[0], (cfg.vocab_size, cfg.d_model)) * 0.02,
         "final_norm": layers.norm_init(cfg.d_model),
         "stages": [
-            _stage_init(jax.random.fold_in(ks[1], si), cfg, pattern, groups,
-                        cross=cfg.is_encoder_decoder)
+            _stage_init(jax.random.fold_in(ks[1], si), cfg.stage_config(si),
+                        pattern, groups, cross=cfg.is_encoder_decoder)
             for si, (pattern, groups) in enumerate(cfg.stages())
         ],
     }
@@ -149,10 +150,62 @@ def _train_flash(attn_impl, seq_len, cfg) -> bool:
         seq_len)
 
 
+def refuse_mla(cfg, what: str) -> None:
+    """Multi-head latent attention runs in train mode only: its serving
+    cache (the latent and the rope key) does not exist here."""
+    if cfg.mla:
+        raise ValueError(
+            f"{cfg.name}: {what} is not supported for multi-head latent "
+            "attention (kv_lora_rank > 0), which runs in train mode only")
+
+
+def _mla_attention(cfg, params, h, positions, attn_impl):
+    """Multi-head latent attention over full sequences, no q compression:
+    q = h W_q, [c ‖ k_pe] = h W_kv_a, c normed, [k_nope ‖ v] = c W_kv_b;
+    q's and k_pe's rope parts rotated (YaRN where configured; k_pe is one
+    head shared by all), causal softmax of the scaled [q_nope ‖ q_pe] ·
+    [k_nope ‖ k_pe], times v, then W_o."""
+    B, S, _ = h.shape
+    H, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    a = params["attn"]
+    with jax.named_scope("mla"):
+        q = (h @ a["wq"].astype(h.dtype)).reshape(B, S, H, dn + dr)
+        ckv = h @ a["wkv_a"].astype(h.dtype)
+        c = layers.rms_norm(a["kv_norm"], ckv[..., :r], cfg.norm_eps)
+        kv = (c @ a["wkv_b"].astype(h.dtype)).reshape(B, S, H, dn + dv)
+        yarn = cfg.rope_yarn
+        if yarn is None:
+            inv, mscale = None, 1.0
+            scale = (dn + dr) ** -0.5
+        else:
+            inv = layers.yarn_inv_freq(dr, cfg.rope_theta, yarn)
+            mscale = (layers.yarn_mscale(yarn.factor, yarn.mscale)
+                      / layers.yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+            scale = layers.yarn_softmax_scale(dn + dr, yarn)
+        q_pe = layers.apply_rope(q[..., dn:], positions, cfg.rope_theta, inv,
+                                 mscale)
+        k_pe = layers.apply_rope(ckv[..., None, r:], positions,
+                                 cfg.rope_theta, inv, mscale)
+        q = jnp.concatenate([q[..., :dn], q_pe], -1)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_pe, (B, S, H, dr))], -1)
+        v = kv[..., dn:]
+        if _train_flash(attn_impl, S, cfg):
+            from repro.kernels import ops as kernel_ops
+            o = kernel_ops.flash_attention(
+                q, k, v, True, 0, attn_impl == "pallas_interpret", scale=scale)
+        else:
+            o = layers.attention_full(q, k, v, positions, positions,
+                                      causal=True, scale=scale)
+        return o.reshape(B, S, H * dv) @ a["wo"].astype(h.dtype)
+
+
 def _block_apply(cfg, btype, params, x, *, positions, mode, cache,
                  enc_out=None, pos=None, attn_impl="chunked",
                  chunk_start=0, page_table=None, active=None, begin=None):
-    """Returns (y, new_cache, aux_loss).
+    """Returns (y, new_cache, stats): ``stats["aux"]`` the auxiliary
+    loss, and an MoE layer's counters (``moe.moe_apply``).
 
     Paged KV (``init_paged_cache``): global-attention block caches are
     ``{"pk", "pv"}`` pools ``[num_pages, page_size, KV, hd]`` addressed
@@ -163,7 +216,18 @@ def _block_apply(cfg, btype, params, x, *, positions, mode, cache,
     prompt position a slot prefills itself (earlier rows come from
     shared prefix pages).
     """
-    aux = jnp.zeros((), jnp.float32)
+    stats = {"aux": jnp.zeros((), jnp.float32)}
+    if btype in ATTN_BLOCKS and cfg.mla:
+        if mode != "train":
+            refuse_mla(cfg, f"mode {mode!r}")
+        h = layers.rms_norm(params["ln1"], x, cfg.norm_eps)
+        x = x + _mla_attention(cfg, params, h, positions, attn_impl)
+        h = layers.rms_norm(params["ln2"], x, cfg.norm_eps)
+        if cfg.num_experts:
+            y, stats = moe_apply_maybe_sharded(params["moe"], h, cfg)
+        else:
+            y = layers.mlp_apply(params["mlp"], h, cfg.mlp_type)
+        return x + y, None, stats
     if btype in ATTN_BLOCKS:
         window = cfg.window_size if btype == BLOCK_LOCAL_ATTN else 0
         h = layers.rms_norm(params["ln1"], x, cfg.norm_eps)
@@ -441,14 +505,14 @@ def _block_apply(cfg, btype, params, x, *, positions, mode, cache,
             x = x + o.reshape(B, S, H * hd) @ params["xattn"]["wo"].astype(x.dtype)
         h = layers.rms_norm(params["ln2"], x, cfg.norm_eps)
         if cfg.num_experts:
-            y, aux = moe_apply_maybe_sharded(params["moe"], h, cfg)
+            y, stats = moe_apply_maybe_sharded(params["moe"], h, cfg)
         elif cfg.d_ff:
             h = shard_ctx.constrain(h, "block_in")
             y = layers.mlp_apply(params["mlp"], h, cfg.mlp_type)
             y = shard_ctx.constrain(y, "residual")
         else:
             y = jnp.zeros_like(h)
-        return x + y, new_cache, aux
+        return x + y, new_cache, stats
 
     if mode in ("prefill_slots", "verify"):
         # recurrent/SSM states would advance on the right-padding of
@@ -463,21 +527,21 @@ def _block_apply(cfg, btype, params, x, *, positions, mode, cache,
         x = x + y
         h = layers.rms_norm(params["ln2"], x, cfg.norm_eps)
         return x + layers.mlp_apply(params["mlp"], h, cfg.mlp_type), \
-            new_cache, aux
+            new_cache, stats
 
     if btype == BLOCK_MLSTM:
         y, new_cache = ssm_parallel.block_shard_map(
             lambda p, xx, c: xlstm.mlstm_block_apply(p, xx, mode=mode,
                                                      cache=c),
             params, x, cache)
-        return x + y, new_cache, aux
+        return x + y, new_cache, stats
 
     if btype == BLOCK_SLSTM:
         y, new_cache = ssm_parallel.block_shard_map(
             lambda p, xx, c: xlstm.slstm_block_apply(p, xx, cfg, mode=mode,
                                                      cache=c),
             params, x, cache)
-        return x + y, new_cache, aux
+        return x + y, new_cache, stats
     raise ValueError(btype)
 
 
@@ -489,6 +553,7 @@ def _block_apply(cfg, btype, params, x, *, positions, mode, cache,
 def init_cache(cfg: ModelConfig, batch: int, seq_len: int,
                dtype=jnp.bfloat16) -> Pytree:
     """Decode cache pytree mirroring the stage/scan structure."""
+    refuse_mla(cfg, "a decode cache")
     KV, hd = cfg.num_kv_heads, cfg.resolved_head_dim
 
     def block_cache(btype):
@@ -537,6 +602,7 @@ def init_paged_cache(cfg: ModelConfig, batch: int, num_pages: int,
     Local-attention blocks keep their dense ring (already bounded by
     the window, and ring indexing is incompatible with page sharing).
     """
+    refuse_mla(cfg, "a paged decode cache")
     KV, hd = cfg.num_kv_heads, cfg.resolved_head_dim
 
     def block_cache(btype):
@@ -644,27 +710,48 @@ def _resolve_overlay(gp, g, ov):
     return out
 
 
+def _zero_stats(cfg):
+    """The stack's totals: the auxiliary loss, and with experts the rows
+    routed to held experts (summed over layers) and the largest group."""
+    z = {"aux": jnp.zeros((), jnp.float32)}
+    if cfg.num_experts:
+        z["moe_rows_held"] = jnp.zeros((), jnp.int32)
+        z["moe_group_max"] = jnp.zeros((), jnp.int32)
+    return z
+
+
+def _add_stats(total, s):
+    out = dict(total)
+    for k, v in s.items():
+        if k in out:
+            out[k] = (jnp.maximum(out[k], v) if k == "moe_group_max"
+                      else out[k] + v.astype(out[k].dtype))
+    return out
+
+
 def _stack_apply(cfg, stage_params, x, *, positions, mode, caches=None,
                  cross_kv=None, enc_present=False, attn_impl="chunked",
                  pos=None, overlay=None, chunk_start=0, page_table=None,
                  active=None, begin=None):
-    """Scan the staged block stack.  Returns (x, new_caches, aux).
+    """Scan the staged block stack.  Returns (x, new_caches, stats) with
+    the totals of ``_zero_stats``.
 
     ``overlay``: optional {sid: {"idx", "rows", "pidx", "probe"}} — the
     BlockLLM active/probe rows, resolved lazily per layer (see
     ``_resolve_overlay``).
     """
-    aux_total = jnp.zeros((), jnp.float32)
+    stats_total = _zero_stats(cfg)
     new_caches = []
     for si, (pattern, groups) in enumerate(cfg.stages()):
+        scfg = cfg.stage_config(si)
         sp = stage_params[si]
         scache = caches[si] if caches is not None else None
         sxkv = cross_kv[si] if cross_kv is not None else None
         sov = {f"pos{j}": (overlay or {}).get(f"s{si}/pos{j}")
                for j in range(len(pattern))}
 
-        def body(carry, per_group):
-            h, aux = carry
+        def body(carry, per_group, scfg=scfg):
+            h, stats = carry
             h = shard_ctx.constrain(h, "residual")  # sequence parallelism
             gp, gc, gx, g = per_group
             new_gc = {}
@@ -677,23 +764,23 @@ def _stack_apply(cfg, stage_params, x, *, positions, mode, caches=None,
                 bp = gp[f"pos{j}"]
                 if sov[f"pos{j}"] is not None:
                     bp = _resolve_overlay(bp, g, sov[f"pos{j}"])
-                h, cj_new, a = _block_apply(
-                    cfg, btype, bp, h, positions=positions,
+                h, cj_new, st = _block_apply(
+                    scfg, btype, bp, h, positions=positions,
                     mode=mode, cache=cj, enc_out=ex, pos=pos,
                     attn_impl=attn_impl, chunk_start=chunk_start,
                     page_table=page_table, active=active, begin=begin)
                 if cj_new is not None:
                     new_gc[f"pos{j}"] = cj_new
-                aux = aux + a
-            return (h, aux), (new_gc if new_gc else None)
+                stats = _add_stats(stats, st)
+            return (h, stats), (new_gc if new_gc else None)
 
         if cfg.remat and mode == "train":
             body = jax.checkpoint(body, prevent_cse=False)
-        (x, aux_total), out_caches = lax.scan(
-            body, (x, aux_total),
+        (x, stats_total), out_caches = lax.scan(
+            body, (x, stats_total),
             (sp, scache, sxkv, jnp.arange(groups, dtype=jnp.int32)))
         new_caches.append(out_caches)
-    return x, new_caches, aux_total
+    return x, new_caches, stats_total
 
 
 # ---------------------------------------------------------------------------
@@ -797,6 +884,16 @@ def _cross_kv(params, cfg, enc_out):
 def forward(params, cfg: ModelConfig, batch, *, mode="train",
             attn_impl="chunked", return_hidden=False, overlay=None):
     """Full-sequence forward.  Returns (logits|hidden, aux, caches|None)."""
+    out, stats, cache = _forward(params, cfg, batch, mode=mode,
+                                 attn_impl=attn_impl,
+                                 return_hidden=return_hidden, overlay=overlay)
+    return out, stats["aux"], cache
+
+
+def _forward(params, cfg, batch, *, mode, attn_impl, return_hidden,
+             overlay):
+    """``forward`` with the stack's totals (``_zero_stats``) in place of
+    the auxiliary loss."""
     tokens = batch["tokens"]
     B, S = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
@@ -805,7 +902,7 @@ def forward(params, cfg: ModelConfig, batch, *, mode="train",
     if cfg.is_encoder_decoder:
         enc_out = _encode(params, cfg, batch["frames"], attn_impl)
         cross_kv = _cross_kv(params, cfg, enc_out)
-    x, caches, aux = _stack_apply(
+    x, caches, stats = _stack_apply(
         cfg, params["stages"], x, positions=positions,
         mode=mode, cross_kv=cross_kv, enc_present=cfg.is_encoder_decoder,
         attn_impl=attn_impl, overlay=overlay)
@@ -815,8 +912,8 @@ def forward(params, cfg: ModelConfig, batch, *, mode="train",
         cache = {"stages": caches}
         if cross_kv is not None:
             cache["cross_kv"] = cross_kv
-        return out, aux, cache
-    return out, aux, None
+        return out, stats, cache
+    return out, stats, None
 
 
 def _labels_mask(batch):
@@ -870,7 +967,10 @@ def _chunked_xent(params, cfg, hidden, labels, mask, chunk):
 
 def loss_fn(params, cfg: ModelConfig, batch, *, attn_impl="chunked",
             loss_chunk=None, overlay=None):
-    """Next-token cross entropy (+ MoE aux).  Returns (loss, metrics).
+    """Next-token cross entropy (+ MoE aux).  Returns (loss, metrics);
+    with experts the metrics also count the rows routed to held experts
+    (``moe_rows_held``, summed over layers) and the largest group
+    (``moe_group_max``).
 
     ``loss_chunk``: None => auto (chunked when S*V is large); 0 => direct.
     """
@@ -879,23 +979,22 @@ def loss_fn(params, cfg: ModelConfig, batch, *, attn_impl="chunked",
     labels, mask = _labels_mask(batch)
     if loss_chunk is None:
         loss_chunk = 512 if S * cfg.vocab_size > (1 << 27) else 0
+    out, stats, _ = _forward(params, cfg, batch, mode="train",
+                             attn_impl=attn_impl,
+                             return_hidden=bool(loss_chunk), overlay=overlay)
     if loss_chunk:
-        hidden, aux, _ = forward(params, cfg, batch, mode="train",
-                                 attn_impl=attn_impl, return_hidden=True,
-                                 overlay=overlay)
-        nll_sum = _chunked_xent(params, cfg, hidden, labels, mask, loss_chunk)
+        nll_sum = _chunked_xent(params, cfg, out, labels, mask, loss_chunk)
     else:
-        logits, aux, _ = forward(params, cfg, batch, mode="train",
-                                 attn_impl=attn_impl, overlay=overlay)
-        nll_sum = _xent_from_logits(logits, labels, mask)
+        nll_sum = _xent_from_logits(out, labels, mask)
     denom = jnp.maximum(mask.sum(), 1.0)
     nll = nll_sum / denom
-    loss = nll + aux
-    metrics = {"nll": nll, "aux": aux, "tokens": mask.sum()}
+    loss = nll + stats["aux"]
+    metrics = {"nll": nll, "tokens": mask.sum(), **stats}
     return loss, metrics
 
 
 def prefill(params, cfg, batch, *, attn_impl="chunked"):
+    refuse_mla(cfg, "prefill")
     logits, _, cache = forward(params, cfg, batch, mode="prefill",
                                attn_impl=attn_impl)
     return logits[:, -1], cache
@@ -906,6 +1005,7 @@ def supports_slot_prefill(cfg: ModelConfig) -> bool:
     rows are position-addressable; recurrent/SSM states would advance on
     right-padding) and a token-only frontend."""
     return (not cfg.is_encoder_decoder and not cfg.vision_embed_dim
+            and not cfg.mla
             and all(t in ATTN_BLOCKS for t in cfg.layer_types()))
 
 
@@ -927,6 +1027,7 @@ def prefill_into_slots(params, cfg: ModelConfig, cache, tokens, lengths,
     ``ceil(P / K)`` dispatches for a whole admitted group instead of P
     whole-model decode dispatches per request.
     """
+    refuse_mla(cfg, "prefill into slots")
     B, K = tokens.shape
     positions = jnp.broadcast_to(
         chunk_start + jnp.arange(K, dtype=jnp.int32)[None], (B, K))
@@ -971,6 +1072,7 @@ def verify_into_slots(params, cfg: ModelConfig, cache, tokens, starts,
     through the same [B, 1, D] matmul shape the decode path uses (fp
     parity; K is small and static).
     """
+    refuse_mla(cfg, "verify")
     B, K = tokens.shape
     starts = jnp.asarray(starts, jnp.int32)
     act = jnp.asarray(active, bool)
@@ -1008,6 +1110,7 @@ def decode_step(params, cfg: ModelConfig, cache, token, pos,
 
     Returns (logits [B, vocab], new_cache).
     """
+    refuse_mla(cfg, "decode")
     pos = jnp.asarray(pos, jnp.int32)
     B = token.shape[0]
     pos_b = jnp.broadcast_to(pos, (B,))

@@ -44,6 +44,9 @@ def _moe_param_specs(params, cfg, mesh, tp_ok: bool):
 
 
 def moe_apply_maybe_sharded(params, x, cfg):
+    """(y, stats): the dropless held-experts layer (``moe.moe_apply``) on
+    one device; the capacity dispatch inside the shard_map below where
+    shard rules ask for it (stats then hold ``aux`` only)."""
     ctx = shard_ctx.get()
     if ctx is None or not ctx.moe_shard_map:
         return moe_lib.moe_apply(params, x, cfg)
@@ -58,7 +61,7 @@ def moe_apply_maybe_sharded(params, x, cfg):
                   or cfg.shared_expert_d_ff % tp_size == 0))
 
     def local(px, xl):
-        y, aux = moe_lib.moe_apply(
+        y, aux = moe_lib.moe_apply_capacity(
             px, xl, cfg, tp_axis=(ctx.tp_axis if tp_ok else None))
         if ndp > 1:
             aux = jax.lax.pmean(aux, dp)
@@ -71,4 +74,5 @@ def moe_apply_maybe_sharded(params, x, cfg):
                   P(dp, None, None)),
         out_specs=(P(dp, None, None), P()),
         check_vma=False)
-    return fn(params, x)
+    y, aux = fn(params, x)
+    return y, {"aux": aux}

@@ -282,7 +282,10 @@ class BlockLLMCore(TrainerCore):
             step_no = int(meta["step"])
             self._ingest_norms(norm_out, plan, params, active, norms,
                                step_no)
-            loss_f = float(loss)
+            # the loss and the step's device metrics (the MoE counters
+            # among them) come over in one transfer
+            loss_f, dev_metrics = jax.device_get((loss, dev_metrics))
+            loss_f = float(loss_f)
             loss_history = list(meta["loss_history"]) + [loss_f]
             step_no += 1
 

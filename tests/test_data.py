@@ -53,3 +53,31 @@ def test_file_source(tmp_path):
     assert b.shape == (2, 32) and b.max() < 256
     b2 = np.asarray(TokenPipeline(cfg).batch(0)["tokens"])
     np.testing.assert_array_equal(b, b2)
+
+
+def _loop_row(seed, step, row, seq, vocab, structure):
+    """The stream token by token: the same draws, each successor from the
+    one before."""
+    import hashlib
+    h = hashlib.blake2b(f"{seed}:{step}:{row}".encode(), digest_size=8)
+    rng = np.random.default_rng(int.from_bytes(h.digest(), "little"))
+    theme = rng.integers(0, structure)
+    toks = [int(rng.integers(0, vocab))]
+    noise, rand = rng.random(seq), rng.integers(0, vocab, seq)
+    for t in range(1, seq):
+        toks.append(int(rand[t]) if noise[t] < 0.15
+                    else (toks[-1] * 31 + int(theme) * 7 + 13) % vocab)
+    return np.array(toks, np.int32)
+
+
+@pytest.mark.parametrize("vocab,seq,structure,seed", [
+    (12800, 2048, 64, 3170170111), (92544, 512, 64, 2 ** 31 + 5),
+    (77, 300, 3, 0), (1, 16, 4, 7), (2 ** 20 + 7, 256, 9, 11)])
+def test_synthetic_rows_equal_the_token_by_token_stream(vocab, seq,
+                                                        structure, seed):
+    cfg = DataConfig(vocab_size=vocab, seq_len=seq, global_batch=3,
+                     seed=seed, structure=structure)
+    got = np.asarray(TokenPipeline(cfg).batch(4)["tokens"])
+    want = np.stack([_loop_row(seed, 4, r, seq, vocab, structure)
+                     for r in range(3)])
+    np.testing.assert_array_equal(got, want)

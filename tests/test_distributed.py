@@ -106,7 +106,7 @@ rules = shard_ctx.ShardRules(mesh=mesh, dp_axes=("data",))
 with shard_ctx.use(rules):
     y_sh, aux_sh = jax.jit(
         lambda p, x: moe_apply_maybe_sharded(p, x, cfg))(p, x)
-y_ref, aux_ref = jax.jit(lambda p, x: moe_lib.moe_apply(
+y_ref, aux_ref = jax.jit(lambda p, x: moe_lib.moe_apply_capacity(
     p, x, cfg, token_chunk=16))(p, x)
 np.testing.assert_allclose(np.asarray(y_sh), np.asarray(y_ref), atol=2e-4)
 print("MOE_SHARDMAP_OK")

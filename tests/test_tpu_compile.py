@@ -6,7 +6,9 @@ chip would refuse — block shapes off the (8, 128) tiling, too much VMEM
 — at no chip time.  The widths are llama-350m's (d=1024, 16 heads,
 hd=64, d_ff 2736, vocab 32000; 8 slots, max-seq 1024, 16-row pages);
 the training flash attention also at the benchmark's InternLM2-1.8B
-shape (2 x 2048 tokens, 16 heads over 8 kv heads, hd=128).
+shape (2 x 2048 tokens, 16 heads over 8 kv heads, hd=128), and
+DeepSeek-V2-Lite's latent attention and held-experts layer at its cell's
+(16 x 2048 tokens; qk 192, v 128; 8 of 64 experts, top 6, width 1408).
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may hold the TPU library, and every test
@@ -134,3 +136,44 @@ def test_scatter_swap_compiles(one_chip, no_persistent_cache, rows, width,
                        ((rows, width), jnp.float32), ((k,), jnp.int32),
                        ((k, width), jnp.float32))
     assert "tpu_custom_call" in hlo
+
+
+def test_mla_flash_attention_fwd_bwd_compiles(one_chip, no_persistent_cache):
+    def fwd_bwd(q, k, v):
+        loss = lambda *a: fa.flash_attention(
+            *a, scale=0.1147).astype(jnp.float32).sum()
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    qk = ((16, 2048, 16, 192), jnp.bfloat16)
+    hlo = _compile_hlo(fwd_bwd, one_chip, qk, qk,
+                       ((16, 2048, 16, 128), jnp.bfloat16))
+    assert hlo.count("tpu_custom_call") >= 3
+
+
+def test_held_experts_layer_fwd_bwd_compiles(one_chip, no_persistent_cache,
+                                             monkeypatch):
+    from repro.configs import base
+    from repro.kernels import ops
+    from repro.models import moe
+    # the layer takes the Pallas grouped matmul where a TPU is attached
+    monkeypatch.setattr(ops, "pallas_available", lambda: True)
+    cfg = base.get_config("deepseek-v2-lite").replace(num_experts=8,
+                                                      router_experts=64)
+    d, f = cfg.d_model, cfg.moe_d_ff
+    shapes = {"router": (d, 64), "w_gate": (8, d, f), "w_up": (8, d, f),
+              "w_down": (8, f, d), "shared": {"w_gate": (d, 2 * f),
+                                              "w_up": (d, 2 * f),
+                                              "w_down": (2 * f, d)}}
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip),
+        shapes, is_leaf=lambda s: isinstance(s, tuple))
+
+    def fwd_bwd(p, x):
+        loss = lambda p, x: moe.moe_apply(p, x, cfg)[0].astype(
+            jnp.float32).sum()
+        return jax.value_and_grad(loss, argnums=(0, 1))(p, x)
+
+    x = jax.ShapeDtypeStruct((16, 2048, d), jnp.bfloat16, sharding=one_chip)
+    hlo = jax.jit(fwd_bwd).lower(params, x).compile().as_text()
+    # gmm for gate, up and down forward; gmm and tgmm for each backward
+    assert hlo.count("tpu_custom_call") >= 9
